@@ -115,5 +115,7 @@ def run() -> List[Row]:
 
 
 if __name__ == "__main__":
+    from repro.exec.jax_cache import use_persistent_cache
+    use_persistent_cache()
     for r in run():
         print(r.csv())

@@ -22,6 +22,15 @@ class Row:
         return f"{self.name},{self.us_per_call:.1f},{self.derived}"
 
 
+def device_record() -> dict:
+    """The devices a measurement ran on, as JAX reports them.  On a CPU
+    platform the Pallas kernels ran in interpret mode."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
 def timed(fn: Callable, *args, repeats: int = 1, **kw):
     t0 = time.perf_counter()
     out = None

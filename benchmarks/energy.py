@@ -202,4 +202,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.exec.jax_cache import use_persistent_cache
+    use_persistent_cache()
     sys.exit(main())

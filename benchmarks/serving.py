@@ -46,7 +46,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 
-from benchmarks.common import Row
+from benchmarks.common import Row, device_record
 from repro.core import perf_model as pm
 from repro.core.types import Backend, Dataflow, PhotonicConfig
 from repro.exec import (PlanCache, ServingEngine, execute_cnn,
@@ -158,7 +158,7 @@ def _measure_network(name: str, cache: PlanCache, reps: int,
                 "dp_ips": dp_ips, "retraces_warm": retraces,
                 "in_hw": IN_HW, "stream_threads": STREAM_THREADS,
                 "smoke": smoke, "bits": cfg.bits,
-                "impl": "pallas(interpret,cpu)"})
+                "device": device_record()})
     return summary, failures
 
 
@@ -251,4 +251,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.exec.jax_cache import use_persistent_cache
+    use_persistent_cache()
     raise SystemExit(main())

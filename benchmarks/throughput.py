@@ -33,7 +33,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 
-from benchmarks.common import Row
+from benchmarks.common import Row, device_record
 from repro.core import perf_model as pm
 from repro.core.types import Backend, Dataflow, PhotonicConfig
 from repro.exec import (PlanCache, compiled_forward, execute_cnn,
@@ -122,7 +122,7 @@ def measure(batches: Sequence[int] = BATCHES,
             extras={"cold_s": cold_s, "warm_s": warm_s,
                     "eager_s": eager_s, "bitexact": bitexact,
                     "retraces_warm": new_traces, "bits": cfg.bits,
-                    "impl": "pallas(interpret,cpu)"})
+                    "device": device_record()})
         summaries.append(summary)
         if save:
             save_summary(summary, EXP_DIR, f"small_cnn_b{batch}.json")
@@ -180,4 +180,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.exec.jax_cache import use_persistent_cache
+    use_persistent_cache()
     raise SystemExit(main())

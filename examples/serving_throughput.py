@@ -82,4 +82,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.exec.jax_cache import use_persistent_cache
+    use_persistent_cache()
     main()

@@ -3,7 +3,8 @@ reduced-scale runnable graphs, planned and executed end-to-end.
 
 For each network: build params from the graph, auto-schedule dataflows
 and tilings, run the compiled Pallas path, and verify the output is
-bit-exact against the pure-jnp oracle with zero warm-call retraces.
+bit-exact against the pure-jnp oracle under the same compilation (the
+compiled forward with the reference GEMM) with zero warm-call retraces.
 
 ``--smoke`` (the CI zoo-smoke gate) runs one ResNet + one MobileNet
 variant and exits non-zero on any conformance violation — the graph
@@ -21,8 +22,7 @@ import numpy as np
 from repro.core import perf_model as pm
 from repro.core.types import Backend, Dataflow, PhotonicConfig
 from repro.exec import (PlanCache, execute_cnn, graph_summary,
-                        plan_for_network, plan_table, reference_forward,
-                        trace_count)
+                        plan_for_network, plan_table, trace_count)
 from repro.models.zoo_cnn import PAPER_ZOO
 
 HEANA = pm.AcceleratorConfig.equal_area("heana", Dataflow.OS, 1.0)
@@ -38,8 +38,9 @@ def run_model(model, batch=2, seed=0, verbose=True) -> bool:
                             lowering=model.graph, cache=PlanCache())
     res = execute_cnn(params, x, plan, cfg, impl="pallas",
                       lowering=model.graph).block_until_ready()
-    ref = reference_forward(params, x, cfg, lowering=model.graph)
-    exact = bool(jnp.all(res.logits == ref))
+    ref = execute_cnn(params, x, plan, cfg, impl="ref",
+                      lowering=model.graph)
+    exact = bool(jnp.all(res.logits == ref.logits))
     before = trace_count()
     execute_cnn(params, x, plan, cfg, impl="pallas", lowering=model.graph)
     no_retrace = trace_count() == before
@@ -77,4 +78,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.exec.jax_cache import use_persistent_cache
+    use_persistent_cache()
     main()

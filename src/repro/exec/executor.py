@@ -52,6 +52,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from repro.core import dataflow as df
 from repro.core import hw
@@ -187,10 +188,10 @@ def _norm_lowering(lowering):
 
 def _layer_matmul(cols: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
                   key: Optional[jax.Array], plan: LayerPlan,
-                  impl: str) -> jnp.ndarray:
+                  impl: str, mesh: Optional[Mesh]) -> jnp.ndarray:
     return ops.photonic_matmul(cols, w, cfg, key=key, impl=impl,
                                block_m=plan.tile.block_m,
-                               block_d=plan.tile.block_d)
+                               block_d=plan.tile.block_d, mesh=mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +216,7 @@ def trace_count() -> int:
 def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
              key: Optional[jax.Array] = None, *,
              lowering, plan: CnnPlan, cfg: PhotonicConfig, impl: str,
-             collect_activations: bool):
+             collect_activations: bool, mesh: Optional[Mesh] = None):
     """Pure forward: (params, x, key) -> (logits, fingerprints, acts).
 
     Walks the lowering's op graph (models.lowering.graph_forward): every
@@ -226,6 +227,8 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     configuration; no host sync happens anywhere in the body
     (fingerprints stay device arrays).  Fingerprints are per GEMM node,
     taken right after its activation (before any downstream glue).
+    ``mesh``: the batch of ``x`` is sharded over it (data-parallel
+    serving); each GEMM kernel then runs per device on its rows.
     """
     global _TRACE_COUNT
     with _TRACE_LOCK:
@@ -237,7 +240,7 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
         layer_key = (jax.random.fold_in(key, gi)
                      if key is not None and cfg.noise_enabled else None)
         return _layer_matmul(a2d, w2d, cfg, layer_key, plan.layers[gi],
-                             impl)
+                             impl, mesh)
 
     vals = lw.graph_forward(params, x, graph, mm)
     gemm_outs = [vals[n.name] for n in graph.gemm_nodes]
@@ -252,11 +255,11 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
 
 
 forward_fn = jax.jit(_forward, static_argnames=(
-    "lowering", "plan", "cfg", "impl", "collect_activations"))
+    "lowering", "plan", "cfg", "impl", "collect_activations", "mesh"))
 """jit entry point: ``forward_fn(params, x, key, lowering=..., plan=...,
-cfg=..., impl=..., collect_activations=...)`` with the keyword arguments
-static — CnnPlan/LayerPlan/TileChoice and PhotonicConfig are hashable by
-value precisely so they can sit in jit's cache key."""
+cfg=..., impl=..., collect_activations=..., mesh=...)`` with the keyword
+arguments static — CnnPlan/LayerPlan/TileChoice and PhotonicConfig are
+hashable by value precisely so they can sit in jit's cache key."""
 
 
 def lowering_fingerprint(lowering) -> str:
@@ -296,8 +299,11 @@ _FORWARD_LOCK = threading.RLock()
 def compiled_forward(plan: CnnPlan, cfg: PhotonicConfig,
                      lowering: Optional[Lowering] = None,
                      impl: str = "auto",
-                     collect_activations: bool = False) -> Callable:
+                     collect_activations: bool = False,
+                     mesh: Optional[Mesh] = None) -> Callable:
     """The compiled serving entry: returns ``fn(params, x, key=None)``.
+
+    ``mesh``: ``x`` will arrive batch-sharded over it (see ``_forward``).
 
     Warm calls execute a cached jit executable — no retracing, no
     per-layer host syncs.  Two plans that solve the same planning problems
@@ -309,13 +315,14 @@ def compiled_forward(plan: CnnPlan, cfg: PhotonicConfig,
     impl = "pallas" if impl == "auto" else impl
     memo_key = (lowering_fingerprint(lowering),
                 tuple(p.cache_key for p in plan.layers), cfg, impl,
-                collect_activations)
+                collect_activations, mesh)
     with _FORWARD_LOCK:
         fn = _FORWARD_CACHE.get(memo_key)
         if fn is None:
             fn = functools.partial(forward_fn, lowering=lowering, plan=plan,
                                    cfg=cfg, impl=impl,
-                                   collect_activations=collect_activations)
+                                   collect_activations=collect_activations,
+                                   mesh=mesh)
             _FORWARD_CACHE[memo_key] = fn
             while len(_FORWARD_CACHE) > _FORWARD_CACHE_MAX:
                 _FORWARD_CACHE.popitem(last=False)
@@ -475,10 +482,13 @@ def reference_forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     runs (models.cnn.lowered_apply) — so the oracle covers any lowered
     network, not just the small CNN.
 
-    The bit-exactness contract (noise disabled): execute_cnn(...,
-    impl='pallas') must equal this exactly — the Pallas path introduces
-    zero numeric deviation, padding included.  A noise-enabled cfg raises
-    (the oracle is deterministic by definition; disable noise explicitly).
+    The bit-exactness contract (noise disabled) is kernel == oracle under
+    the same compilation: execute_cnn(..., impl='pallas') equals
+    execute_cnn(..., impl='ref') exactly, one jitted program with only
+    the GEMM swapped.  This eager forward is another program, whose glue
+    XLA may round differently (1 ULP at googlenet_mini's global average
+    on the CPU backend).  A noise-enabled cfg raises (the oracle is
+    deterministic by definition; disable noise explicitly).
     """
     mm: Callable = lambda a, w: ops.photonic_matmul(a, w, cfg, impl="ref")
     return cnn_mod.lowered_apply(params, x, _norm_lowering(lowering),

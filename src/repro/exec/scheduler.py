@@ -46,15 +46,18 @@ from repro.exec import plan_cache as pc
 # so choose_tile cannot drift from what taom_gemm_quantized actually runs.
 from repro.kernels.taom_gemm import LANE as _LANE
 from repro.kernels.taom_gemm import SUBLANE as _SUBLANE
-from repro.kernels.taom_gemm import _round_up
+from repro.kernels.taom_gemm import VMEM_BUDGET_BYTES as _VMEM_BUDGET
+from repro.kernels.taom_gemm import _round_up, vmem_bytes
 from repro.models.cnn import LayerGemm
 
-# Large-M tiles matter for executor throughput: the kernel's grid loop is
-# serialized over M/block_m steps, so a batch-256 conv (M = 65536 rows)
-# at block_m=256 pays 256 grid steps where block_m=4096 pays 16 — ~10x
-# wall-clock on the serving hot path.  Padding waste still dominates the
-# choice, so small layers keep small tiles; an (8, 4096) f32 block stays
-# comfortably inside TPU VMEM budgets.
+# Large-M tiles cut grid steps: a batch-256 conv (M = 65536 rows) at
+# block_m=256 pays 256 grid steps where block_m=4096 pays 16.  That
+# ordering was tuned on interpret-mode wall-clock, not chip kernel times.
+# Padding waste still dominates the choice, so small layers keep small
+# tiles.  Large tiles do NOT all fit the chip: the kernel double-buffers
+# (block_m, K-slot) and (block_m, block_d) f32 blocks, and (4096, 256)
+# needs about 30 MiB of VMEM on v5e.  choose_tile therefore admits only
+# tiles whose kernels.taom_gemm.vmem_bytes fits VMEM_BUDGET_BYTES.
 _BLOCK_M_CANDIDATES = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 _BLOCK_D_CANDIDATES = (128, 256)
 # v3: depthwise (count>1, d=1) layers choose their tile for the GEMM the
@@ -207,15 +210,19 @@ class CnnPlan:
 def choose_tile(m: int, d: int, k: int, dpe_size: int) -> TileChoice:
     """Pick the kernel (block_m, block_d) for an (M, D) output.
 
-    Minimize padded-output elements first (don't burn MXU cycles on
-    padding), then grid steps (fewer, larger tiles win ties).  Mirrors the
-    kernel's own clamping so grid numbers here are exactly what it runs.
+    Only tiles whose kernel footprint (kernels.taom_gemm.vmem_bytes)
+    fits the kernel's VMEM budget are admitted.  Among those, minimize
+    padded-output elements first (don't burn MXU cycles on padding), then
+    grid steps (fewer, larger tiles win ties).  Mirrors the kernel's own
+    clamping so grid numbers here are exactly what it runs.
     """
     best = None
     for bm in _BLOCK_M_CANDIDATES:
         bm_eff = min(bm, _round_up(m, _SUBLANE))
         for bd in _BLOCK_D_CANDIDATES:
             bd_eff = min(bd, _round_up(d, _LANE))
+            if vmem_bytes(bm_eff, bd_eff, dpe_size) > _VMEM_BUDGET:
+                continue
             mp, dp = _round_up(m, bm_eff), _round_up(d, bd_eff)
             grid_m, grid_d = mp // bm_eff, dp // bd_eff
             score = (mp * dp, grid_m * grid_d, bm_eff, bd_eff)
@@ -223,6 +230,9 @@ def choose_tile(m: int, d: int, k: int, dpe_size: int) -> TileChoice:
                 waste = mp * dp / float(m * d) - 1.0
                 best = (score, TileChoice(bm_eff, bd_eff, grid_m, grid_d,
                                           max(1, -(-k // dpe_size)), waste))
+    if best is None:
+        raise ValueError(f"no kernel tile for dpe_size={dpe_size} fits the "
+                         f"{_VMEM_BUDGET} B VMEM budget")
     return best[1]
 
 
@@ -236,6 +246,7 @@ def _cache_payload(g: df.GemmShape, count: int, acc: pm.AcceleratorConfig,
         "objective": objective,
         "flows": sorted(f.value for f in flows),
         "tiles": [_BLOCK_M_CANDIDATES, _BLOCK_D_CANDIDATES],
+        "vmem_budget": _VMEM_BUDGET,
     }
 
 

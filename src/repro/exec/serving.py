@@ -35,11 +35,13 @@ implies:
   * a **multi-device data-parallel path** — the bucketed batch is placed
     on a NamedSharding over the image batch axis of a 1-D ('data',) mesh
     (the spirit of parallel/sharding.py's batch_sharding) and the
-    already-jitted forward is GSPMD-partitioned by XLA.  Because the
-    contraction (K) axis is never sharded and the global quantize-scale
-    max becomes an exact all-reduce max, the data-parallel logits are
-    BITWISE equal to single-device execution when noise is off
-    (benchmarks/serving.py checks this on 4 virtual CPU devices);
+    already-jitted forward is GSPMD-partitioned by XLA, except the Pallas
+    kernels, which XLA cannot partition: each runs per device on its own
+    rows (shard_map).  Because the contraction (K) axis is never sharded
+    and the global quantize-scale max becomes an exact all-reduce max,
+    the data-parallel logits are BITWISE equal to single-device
+    execution when noise is off (benchmarks/serving.py checks this on 4
+    virtual CPU devices, chip_smoke.py --chips 4 on four TPU chips);
 
   * **serving metrics** — p50/p99 request latency, sustained throughput,
     padding-overhead fraction, the plan/compile cache stats surfaced
@@ -170,11 +172,6 @@ class ServingEngine:
         # them.  (The executor re-checks per request via _validate — this
         # just moves the clear error to construction time.)
         hw.check_kernel_plan_coherence(cfg, self.plans[self.buckets[0]])
-        # One compiled wrapper per bucket, built up front: the jit
-        # executables themselves materialize at warmup()/first call.
-        self._fns = {b: ex.compiled_forward(self.plans[b], cfg,
-                                            self._lowering, impl)
-                     for b in self.buckets}
 
         self.devices = (list(devices) if devices is not None
                         else list(jax.devices()))
@@ -184,12 +181,19 @@ class ServingEngine:
                 "data_parallel serving requires noise_enabled=False — "
                 "per-shard noise streams would not reproduce the "
                 "single-device stream (run noisy inference single-device)")
+        self._mesh = None
         if self.data_parallel:
             self._mesh = Mesh(np.asarray(self.devices), ("data",))
             self._x_sharding = NamedSharding(self._mesh,
                                              P("data", None, None, None))
             self._params_dp = jax.device_put(
                 params, NamedSharding(self._mesh, P()))
+        # One compiled wrapper per bucket, built up front: the jit
+        # executables themselves materialize at warmup()/first call.
+        self._fns = {b: ex.compiled_forward(
+            self.plans[b], cfg, self._lowering, impl,
+            mesh=self._mesh if self._dp_bucket(b) else None)
+            for b in self.buckets}
 
         self._lock = threading.Lock()
         self._latencies: List[float] = []

@@ -16,6 +16,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.photonic_gemm import sample_noise, noise_shape
 from repro.core.taom import quantize
@@ -34,14 +35,25 @@ def _on_cpu() -> bool:
 # ---------------------------------------------------------------------------
 def _taom_forward(x2d: jnp.ndarray, w: jnp.ndarray, noise: jnp.ndarray,
                   cfg: PhotonicConfig, adc_fs: float, impl: str,
-                  blocks: tuple) -> jnp.ndarray:
+                  blocks: tuple, mesh: Optional[Mesh]) -> jnp.ndarray:
     f32 = jnp.float32
     xq, sx = quantize(x2d.astype(f32), cfg.bits, axis=None)
     wq, sw = quantize(w.astype(f32), cfg.bits, axis=0)
     if impl == "pallas":
-        acc = taom_kernel_mod.taom_gemm_quantized(
-            xq, wq, noise, cfg, adc_fs, block_m=blocks[0], block_d=blocks[1],
-            interpret=_on_cpu())
+        kernel = functools.partial(
+            taom_kernel_mod.taom_gemm_quantized, cfg=cfg, adc_fs=adc_fs,
+            block_m=blocks[0], block_d=blocks[1], interpret=_on_cpu())
+        if mesh is not None:
+            # XLA cannot partition a Mosaic kernel: each device runs it on
+            # its own rows.  Rows are independent, so this is bitwise the
+            # unsharded kernel; the quantize max above stays global.
+            rows = P(mesh.axis_names)
+            kernel = jax.shard_map(
+                kernel, mesh=mesh,
+                in_specs=(rows, P(), rows if noise.ndim == 2
+                          else P(None, mesh.axis_names)),
+                out_specs=rows, check_vma=False)
+        acc = kernel(xq, wq, noise)
     else:
         acc = ref_mod.taom_gemm_reference(xq, wq, noise, cfg, adc_fs)
     # Pin the rescale against XLA's algebraic simplifier: under
@@ -56,16 +68,17 @@ def _taom_forward(x2d: jnp.ndarray, w: jnp.ndarray, noise: jnp.ndarray,
     return jax.lax.optimization_barrier(out)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _taom_ste(x2d, w, noise, cfg, adc_fs, impl, blocks):
-    return _taom_forward(x2d, w, noise, cfg, adc_fs, impl, blocks)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _taom_ste(x2d, w, noise, cfg, adc_fs, impl, blocks, mesh):
+    return _taom_forward(x2d, w, noise, cfg, adc_fs, impl, blocks, mesh)
 
 
-def _taom_ste_fwd(x2d, w, noise, cfg, adc_fs, impl, blocks):
-    return _taom_forward(x2d, w, noise, cfg, adc_fs, impl, blocks), (x2d, w)
+def _taom_ste_fwd(x2d, w, noise, cfg, adc_fs, impl, blocks, mesh):
+    return (_taom_forward(x2d, w, noise, cfg, adc_fs, impl, blocks, mesh),
+            (x2d, w))
 
 
-def _taom_ste_bwd(cfg, adc_fs, impl, blocks, res, g):
+def _taom_ste_bwd(cfg, adc_fs, impl, blocks, mesh, res, g):
     x2d, w = res
     return (g @ w.T).astype(x2d.dtype), (x2d.T @ g).astype(w.dtype), None
 
@@ -77,7 +90,8 @@ def photonic_matmul(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
                     key: Optional[jax.Array] = None,
                     impl: str = "auto",
                     adc_fs: Optional[float] = None,
-                    block_m: int = 128, block_d: int = 128) -> jnp.ndarray:
+                    block_m: int = 128, block_d: int = 128,
+                    mesh: Optional[Mesh] = None) -> jnp.ndarray:
     """Photonic-numerics matmul: (..., K) @ (K, D) -> (..., D).
 
     Arbitrary leading batch dims fold into the GEMM M axis (the
@@ -88,6 +102,8 @@ def photonic_matmul(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
     adc_fs: calibrated PGA full scale; default = analytic calibration.
     block_m/block_d: kernel output-tile sizes (a LayerPlan's tiling choice
     from repro.exec.scheduler plugs in here; numerics are tile-invariant).
+    mesh: when the rows of ``x`` are sharded over this mesh (data-parallel
+    serving), the Pallas kernel runs per device on its rows.
 
     jit-friendly: every branch here is on static config (cfg, impl, key
     being None), so the whole call traces into one compiled program —
@@ -124,7 +140,7 @@ def photonic_matmul(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
     if cfg.backend in (Backend.AMW, Backend.MAW):
         noise = jnp.moveaxis(noise, -2, 0)   # (..., C, D) -> (C, M, D)
     out = _taom_ste(x2d, w, noise, cfg, float(adc_fs), impl,
-                    (int(block_m), int(block_d)))
+                    (int(block_m), int(block_d)), mesh)
     return out.reshape(*batch_shape, w.shape[-1])
 
 

@@ -18,7 +18,7 @@ from repro.core.types import Backend, PhotonicConfig
 # all: both sides compute the same host-side step/reciprocal constants, so
 # the oracle cannot diverge from the kernel by a compile-mode ULP (see
 # adc_round's docstring).
-from repro.kernels.taom_gemm import adc_round, chunk_fs
+from repro.kernels.taom_gemm import adc_code, adc_round, adc_step, chunk_fs
 
 
 def taom_gemm_reference(xq: jnp.ndarray, wq: jnp.ndarray,
@@ -44,8 +44,8 @@ def taom_gemm_reference(xq: jnp.ndarray, wq: jnp.ndarray,
     if cfg.backend in (Backend.AMW, Backend.MAW):
         assert noise.shape == (n_chunks, m, d)
         noisy = psums + sigma * noise
-        quant = adc_round(noisy, cfg.adc_bits, chunk_fs(cfg))
-        return jnp.sum(quant, axis=0)
+        codes = adc_code(noisy, cfg.adc_bits, chunk_fs(cfg))
+        return jnp.sum(codes, axis=0) * adc_step(cfg.adc_bits, chunk_fs(cfg))
     assert noise.shape == (m, d)
     acc = jnp.sum(psums, axis=0)
     acc = acc + sigma * math.sqrt(float(n_chunks)) * noise

@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
                 state_ref, *, n_chunks: int):
@@ -113,7 +109,7 @@ def ssd_scan_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
             jax.ShapeDtypeStruct((bh, p, s), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, s), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a2, b, c)

@@ -37,13 +37,38 @@ from repro.core.types import Backend, PhotonicConfig
 LANE = 128
 SUBLANE = 8
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
+#: Scoped VMEM the kernel is compiled under (``vmem_limit_bytes``) and
+#: that exec.scheduler.choose_tile admits tiles against.  16 MiB is the
+#: v5e compiler's default scoped limit.  Stating it explicitly makes the
+#: kernel's own footprint the only thing that decides whether a tile
+#: compiles: under the implicit default the compiler refused some tiles
+#: depending on the surrounding program (memory-space assignment of the
+#: custom call's operands), not on the kernel.
+VMEM_BUDGET_BYTES = 16 * 2 ** 20
+# Readout temporaries beyond the blocks (the noisy accumulator and its
+# ADC-rounded value), in (block_m, block_d) f32 tiles.  v5e compiles put
+# the largest at 1.41 tiles (analog-carry readout at (4096, 256));
+# tests/test_chip_compile.py compiles the largest admitted tiles.
+_READOUT_TILES = 1.5
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def vmem_bytes(block_m: int, block_d: int, dpe_size: int) -> int:
+    """VMEM footprint of one grid step of ``taom_gemm_quantized``.
+
+    Counts the kernel's BlockSpecs — x (1, bm, slot), w (1, slot, bd),
+    noise (bm, bd) and out (bm, bd), all f32 and double-buffered by the
+    pipeline — plus the (bm, bd) accumulator scratch and the readout
+    temporaries.  ``block_m``/``block_d`` are the tiles the kernel runs
+    (after its clamping to the GEMM), ``slot`` the lane-padded chunk.
+    """
+    slot = _round_up(dpe_size, LANE)
+    tile = block_m * block_d
+    blocks = block_m * slot + slot * block_d + 2 * tile
+    return int(4 * (2 * blocks + (1 + _READOUT_TILES) * tile))
 
 
 def adc_round(v: jnp.ndarray, adc_bits: int, full_scale: float) -> jnp.ndarray:
@@ -58,13 +83,22 @@ def adc_round(v: jnp.ndarray, adc_bits: int, full_scale: float) -> jnp.ndarray:
     is bit-identical under both, and kernels/ref.py shares this exact
     function so kernel and oracle cannot diverge either.
     """
-    levels = (1 << adc_bits) - 1
+    return adc_code(v, adc_bits, full_scale) * adc_step(adc_bits, full_scale)
+
+
+def adc_step(adc_bits: int, full_scale: float) -> float:
+    """One ADC code's width, a host-side double (see ``adc_round``)."""
     # Same degenerate-input floor as core.bpca.adc_readout: a zero/negative
     # programmed full scale clamps instead of dividing by zero.
-    step = 2.0 * max(float(full_scale), 1e-12) / levels
-    inv_step = 1.0 / step
+    return 2.0 * max(float(full_scale), 1e-12) / ((1 << adc_bits) - 1)
+
+
+def adc_code(v: jnp.ndarray, adc_bits: int, full_scale: float) -> jnp.ndarray:
+    """The ADC's output code for ``v``: integer-valued f32 in [-hi, hi]."""
+    levels = (1 << adc_bits) - 1
     hi = levels // 2 + levels % 2
-    return jnp.clip(jnp.round(v * inv_step), -hi, hi) * step
+    return jnp.clip(jnp.round(v * (1.0 / adc_step(adc_bits, full_scale))),
+                    -hi, hi)
 
 
 def calibrated_adc_fs(k: int, cfg: PhotonicConfig) -> float:
@@ -102,7 +136,12 @@ def _kernel_analog_carry(x_ref, w_ref, noise_ref, out_ref, acc_ref, *,
 def _kernel_chunk_adc(x_ref, w_ref, noise_ref, out_ref, acc_ref, *,
                       n_chunks: int, sigma: float, adc_bits: int,
                       fs_chunk: float):
-    """AMW/MAW policy: per-chunk noise + ADC rounding, digital reduction."""
+    """AMW/MAW policy: per-chunk noise + ADC rounding, digital reduction.
+
+    The digital adder sums integer ADC codes, scaled by the code width
+    once at readout: the sum is exact in any order, so the oracle's
+    reduction cannot differ from the kernel's in the last bits.
+    """
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -110,11 +149,11 @@ def _kernel_chunk_adc(x_ref, w_ref, noise_ref, out_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     psum = jnp.dot(x_ref[0], w_ref[0], preferred_element_type=jnp.float32)
-    acc_ref[...] += adc_round(psum + sigma * noise_ref[0], adc_bits, fs_chunk)
+    acc_ref[...] += adc_code(psum + sigma * noise_ref[0], adc_bits, fs_chunk)
 
     @pl.when(c == n_chunks - 1)
     def _readout():
-        out_ref[...] = acc_ref[...]   # chunk psums already quantized
+        out_ref[...] = acc_ref[...] * adc_step(adc_bits, fs_chunk)
 
 
 def taom_gemm_quantized(xq: jnp.ndarray, wq: jnp.ndarray,
@@ -188,8 +227,9 @@ def taom_gemm_quantized(xq: jnp.ndarray, wq: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bd), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, dp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bd), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_BUDGET_BYTES),
         interpret=interpret,
     )(x2, wq_c, noise_p)
     return out[:m, :d]

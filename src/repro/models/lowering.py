@@ -474,9 +474,28 @@ def graph_gemms(graph: OpGraph, in_hw,
 # ---------------------------------------------------------------------------
 # Forward walkers
 # ---------------------------------------------------------------------------
+def _global_mean(x: jnp.ndarray) -> jnp.ndarray:
+    """Global average pool as a fixed tree of pairwise adds.
+
+    A reduce sums in the order of the physical layout XLA gives its
+    operand, and that layout depends on the surrounding program: on TPU a
+    Pallas kernel's output and an XLA dot's operand get different ones.
+    Two compilations of one network then disagree in the last bit of the
+    pooled value, and so in the classifier's quantize scale.  Elementwise
+    adds in a fixed pairing round the same in every program.
+    """
+    n, h, w, c = x.shape
+    v = x.reshape(n, h * w, c)
+    while v.shape[1] > 1:
+        half = v.shape[1] // 2
+        v = jnp.concatenate([v[:, :half] + v[:, half:2 * half],
+                             v[:, 2 * half:]], axis=1)
+    return (v * (1.0 / (h * w))).reshape(n, 1, 1, c)
+
+
 def _apply_pool(node: OpNode, x: jnp.ndarray) -> jnp.ndarray:
     if node.pool == "global":
-        return jnp.mean(x, axis=(1, 2), keepdims=True)
+        return _global_mean(x)
     s, st = node.pool_size, node.pool_stride
     pad = "SAME" if node.padding == "same" else "VALID"
     if node.pool == "max":

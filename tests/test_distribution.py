@@ -19,12 +19,9 @@ from repro.parallel import sharding as shd
 
 
 def _abstract_mesh():
-    """16x16 (data, model) AbstractMesh across jax signature versions."""
+    """16x16 (data, model) AbstractMesh."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((("data", 16), ("model", 16)))
-    except TypeError:                      # older (shape, names) signature
-        return AbstractMesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 class TestSpecMapping:

@@ -4,7 +4,9 @@ For EVERY zoo network (the four paper-CNN reduced variants + the small
 CNN):
 
   * the compiled Pallas executor output is BIT-EXACT vs the pure-jnp
-    oracle (kernels/ref.py via reference_forward) with noise off;
+    oracle (kernels/ref.py) under the same compilation — the executor's
+    jitted forward with the reference GEMM in place of the kernel — with
+    noise off;
   * warm compiled calls never retrace (trace_count pins it per model);
   * the runnable graph's GEMM table equals the paper-style analytic
     accounting (models.cnn._conv/_dw formulas — what feeds
@@ -28,7 +30,7 @@ import pytest
 from repro.core import perf_model as pm
 from repro.core.types import Backend, Dataflow, PhotonicConfig
 from repro.exec import (PlanCache, execute_cnn, graph_summary,
-                        plan_for_network, reference_forward, trace_count)
+                        plan_for_network, trace_count)
 from repro.models import cnn, lowering as lw
 from repro.models.zoo_cnn import PAPER_ZOO, ZOO
 
@@ -58,13 +60,19 @@ class TestZooConformance:
 
     @pytest.mark.parametrize("name", list(ZOO))
     def test_compiled_pallas_bit_exact_vs_oracle(self, name):
+        """Kernel == oracle under the same compilation (jit vs jit): the
+        same jitted program with only the GEMM swapped.  A separately
+        jitted reference_forward is another program, and XLA may fuse
+        its glue differently (googlenet_mini's global-average reduce
+        drifts by 1 ULP on the CPU backend)."""
         model = ZOO[name]
         params, x, plan = _setup(model)
         res = execute_cnn(params, x, plan, _cfg(), impl="pallas",
                           lowering=model.graph)
-        ref = reference_forward(params, x, _cfg(), lowering=model.graph)
+        ref = execute_cnn(params, x, plan, _cfg(), impl="ref",
+                          lowering=model.graph)
         np.testing.assert_array_equal(np.asarray(res.logits),
-                                      np.asarray(ref))
+                                      np.asarray(ref.logits))
         assert res.logits.shape == (2, model.num_classes)
 
     @pytest.mark.parametrize("name", list(ZOO))
