@@ -165,18 +165,17 @@ def serving_table() -> str:
                "benchmarks.serving` to generate)*"
     lines = [
         "| cnn | devs | bucket | bucketed img/s | per-req img/s | speedup "
-        "| p50 ms | p99 ms | padding | dp bit-exact | retraces (warm) |",
-        "|---|---|---|---|---|---|---|---|---|---|---|",
+        "| mean ms | padding | dp bit-exact | retraces (warm) |",
+        "|---|---|---|---|---|---|---|---|---|---|",
     ]
     for r in sorted(recs, key=lambda r: (r["name"],
                                          r.get("n_devices") or 1)):
-        p50, p99 = r.get("latency_p50_s"), r.get("latency_p99_s")
+        mean = r.get("latency_mean_s")
         lines.append(
             f"| {r['name']} | {r.get('n_devices', '-')} | {r['bucket']} | "
             f"{_fmt(r['bucketed_ips'])} | "
             f"{_fmt(r['per_request_ips'])} | {r['speedup']:.1f}x | "
-            f"{f'{p50 * 1e3:.1f}' if p50 is not None else '-'} | "
-            f"{f'{p99 * 1e3:.1f}' if p99 is not None else '-'} | "
+            f"{f'{mean * 1e3:.1f}' if mean is not None else '-'} | "
             f"{_fmt(r.get('padding_fraction'))} | "
             f"{r.get('dp_bitexact', '-')} | "
             f"{r.get('retraces_warm', '-')} |")

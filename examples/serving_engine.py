@@ -98,9 +98,11 @@ def main():
     print("\n== serving stats ==")
     print(f"   requests {s['requests']}, images {s['images']}, "
           f"batches {s['batches']}")
-    print(f"   latency p50 {s['latency_p50_s'] * 1e3:.1f} ms, "
-          f"p99 {s['latency_p99_s'] * 1e3:.1f} ms; sustained "
-          f"{s['sustained_ips']:,.0f} img/s (host sim)")
+    print(f"   mean service time {s['latency_mean_s'] * 1e3:.1f} ms; "
+          f"{s['infer_s_total'] * 1e3:.0f} ms inside infer in all, "
+          f"{s['device_wait_s_total'] * 1e3:.0f} ms of it waiting for "
+          f"the device; sustained {s['sustained_ips']:,.0f} img/s "
+          f"(host sim)")
     print(f"   padding overhead {100 * s['padding_fraction']:.1f}% of "
           f"executed slots; retraces since warmup "
           f"{s['retraces_since_warmup']}")

@@ -247,9 +247,12 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     # mean |activation| via explicit reciprocal multiply — jnp.mean's
     # division by the (constant) element count is reassociated by XLA
     # under jit but not eagerly, and the compiled-vs-eager contract
-    # covers the fingerprints too.
-    fingerprints = [jnp.sum(jnp.abs(v)) * (1.0 / v.size)
-                    for v in gemm_outs]
+    # covers the fingerprints too.  Each is named by its node, like the
+    # node's own operations.
+    fingerprints = []
+    for node, v in zip(graph.gemm_nodes, gemm_outs):
+        with jax.named_scope(node.name):
+            fingerprints.append(jnp.sum(jnp.abs(v)) * (1.0 / v.size))
     acts = tuple(gemm_outs) if collect_activations else ()
     return (vals[graph.output.name], jnp.stack(fingerprints), acts)
 

@@ -156,7 +156,7 @@ def serving_summary(name: str, batch_bucket: int, engine_stats: dict,
     cell; ``per_request_ips`` is the single-image-at-a-time baseline
     (batch-1 plan, one compiled call per image) the bucketed path is
     amortizing away.  ``engine_stats`` is ServingEngine.stats() — the
-    padding/latency/cache evidence rides along verbatim.
+    padding, service-time and cache evidence rides along verbatim.
     """
     out = {
         "kind": "serving",
@@ -166,8 +166,8 @@ def serving_summary(name: str, batch_bucket: int, engine_stats: dict,
         "per_request_ips": per_request_ips,
         "speedup": (bucketed_ips / per_request_ips) if per_request_ips > 0
         else float("inf"),
-        "latency_p50_s": engine_stats.get("latency_p50_s"),
-        "latency_p99_s": engine_stats.get("latency_p99_s"),
+        "latency_mean_s": engine_stats.get("latency_mean_s"),
+        "device_wait_s_total": engine_stats.get("device_wait_s_total"),
         "padding_fraction": engine_stats.get("padding_fraction"),
         "retraces_since_warmup": engine_stats.get("retraces_since_warmup"),
         "data_parallel": engine_stats.get("data_parallel"),

@@ -43,12 +43,21 @@ implies:
     execution when noise is off (benchmarks/serving.py checks this on 4
     virtual CPU devices, chip_smoke.py --chips 4 on four TPU chips);
 
-  * **serving metrics** — p50/p99 request latency, sustained throughput,
-    padding-overhead fraction, the plan/compile cache stats surfaced
-    from the existing ``stats()`` hooks, and the photonic model's energy
-    accounting of the served stream (modeled joules per inference —
-    padding included, that's the cost of bucketing — and sustained
-    watts), derived from each bucket plan via core.hw.trace_energy.
+  * **serving metrics** — always-on counters (mean service time and
+    sustained throughput from the total time inside ``infer``, the time
+    spent waiting for the device, the micro-batcher's queue wait and its
+    host time per batch), padding-overhead fraction, the plan/compile
+    cache stats surfaced from the existing ``stats()`` hooks, and the
+    photonic model's energy accounting of the served stream (modeled
+    joules per inference — padding included, that's the cost of
+    bucketing — and sustained watts), derived from each bucket plan via
+    core.hw.trace_energy.
+
+Spans: with ``exec.spans.record(True)`` every call records the host
+spans of the path (``engine.*`` in ``infer``; ``batcher.*`` on the
+micro-batcher's worker, plus one ``batcher.queue_wait`` per request) on
+the wall clock a profiler trace is placed by; ``exec.spans.drain()``
+returns them.  The counters above come from the same clock reads.
 
 Noise: a noise-enabled engine requires a root PRNG key per ``infer`` call
 (per-chunk keys are folded in, per-layer keys inside the forward).  The
@@ -57,11 +66,12 @@ diverge from the single-device stream, silently breaking reproducibility.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
 from concurrent.futures import Future
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -72,14 +82,12 @@ from repro.core import hw
 from repro.core.types import PhotonicConfig
 from repro.exec import executor as ex
 from repro.exec import plan_cache as pc
+from repro.exec import spans
 from repro.exec.scheduler import CnnPlan, HardwareSpec, schedule_buckets
 from repro.models import cnn as cnn_mod
 
 __all__ = ["ServingEngine", "MicroBatcher", "power_of_two_buckets",
            "bucket_for"]
-
-#: How many recent request latencies the metrics window keeps.
-_LATENCY_WINDOW = 16384
 
 
 def power_of_two_buckets(max_batch: int) -> Tuple[int, ...]:
@@ -101,13 +109,6 @@ def bucket_for(n: int, buckets: Sequence[int]) -> int:
     raise ValueError(f"batch {n} exceeds the largest bucket "
                      f"{buckets[-1]} — the engine chunks before bucketing, "
                      f"so this is an internal error")
-
-
-def _percentile(sorted_vals: Sequence[float], q: float) -> float:
-    if not sorted_vals:
-        return 0.0
-    idx = min(len(sorted_vals) - 1, int(q * len(sorted_vals)))
-    return sorted_vals[idx]
 
 
 class ServingEngine:
@@ -196,14 +197,18 @@ class ServingEngine:
             for b in self.buckets}
 
         self._lock = threading.Lock()
-        self._latencies: List[float] = []
         self._requests = 0
         self._images = 0
+        self._blocked_requests = 0
         self._blocked_images = 0
         self._batches = 0
         self._padded_slots = 0
         self._executed_slots = 0
-        self._busy_s = 0.0
+        self._infer_ns = 0
+        self._device_wait_ns = 0
+        # Each thread's last blocking infer's device wait, for the
+        # micro-batcher's host time per batch.
+        self._local = threading.local()
         self._warm = False
         self._retraces = 0
         # Modeled photonic energy of the executed stream: per-bucket
@@ -242,17 +247,22 @@ class ServingEngine:
                     self._retraces += traced
         return logits
 
-    def _infer_chunk(self, chunk: jnp.ndarray, key) -> jnp.ndarray:
-        n = chunk.shape[0]
+    def _infer_chunk(self, x: jnp.ndarray, start: int, n: int,
+                     key) -> jnp.ndarray:
         bucket = bucket_for(n, self.buckets)
         pad = bucket - n
-        xb = (chunk if pad == 0 else jnp.concatenate(
-            [chunk, jnp.zeros((pad,) + chunk.shape[1:], chunk.dtype)]))
+        with spans.span("engine.pad"):
+            chunk = x[start:start + n]
+            xb = (chunk if pad == 0 else jnp.concatenate(
+                [chunk, jnp.zeros((pad,) + chunk.shape[1:], chunk.dtype)]))
         # The executor's own eager validation surfaces its clear errors
         # (geometry mismatch, noise-without-key) through the serving
         # entry point, before anything touches the compiled path.
-        ex._validate(xb, self.plans[bucket], self._cfg, self._lowering, key)
-        logits = self._run_bucket(xb, key, bucket)
+        with spans.span("engine.validate"):
+            ex._validate(xb, self.plans[bucket], self._cfg, self._lowering,
+                         key)
+        with spans.span("engine.dispatch"):
+            logits = self._run_bucket(xb, key, bucket)
         te = self._bucket_energy[bucket]
         with self._lock:
             self._batches += 1
@@ -260,7 +270,8 @@ class ServingEngine:
             self._executed_slots += bucket
             self._energy_j += te.energy_j
             self._model_time_s += te.latency_s
-        return logits[:n] if pad else logits
+        with spans.span("engine.slice"):
+            return logits[:n] if pad else logits
 
     # -- public entry points -------------------------------------------------
     def warmup(self, key: Optional[jax.Array] = None) -> Dict[int, float]:
@@ -296,10 +307,26 @@ class ServingEngine:
         the recorded latency is true request latency; ``block=False``
         returns the dispatched arrays immediately — such calls still
         count toward request/image/padding totals but are EXCLUDED from
-        the latency percentiles and sustained_ips (a dispatch-only
-        duration is not a request latency).
+        ``infer_s_total`` and the figures derived from it (a
+        dispatch-only duration is not a request latency).
         """
-        t0 = time.perf_counter()
+        self._local.device_wait_ns = 0
+        with spans.span("engine.infer") as whole:
+            logits = self._infer(x, key, block)
+        wait_ns = self._local.device_wait_ns
+        n = logits.shape[0]
+        with self._lock:
+            self._requests += 1
+            self._images += n
+            if block:
+                self._blocked_requests += 1
+                self._blocked_images += n
+                self._infer_ns += whole.t1 - whole.t0
+                self._device_wait_ns += wait_ns
+        return logits
+
+    def _infer(self, x, key: Optional[jax.Array],
+               block: bool) -> jnp.ndarray:
         x = jnp.asarray(x)
         if x.ndim != 4:
             raise ValueError(f"x must be (N, H, W, C) images, got shape "
@@ -317,22 +344,18 @@ class ServingEngine:
             take = min(self.max_bucket, n - start)
             ck = (jax.random.fold_in(key, ci)
                   if key is not None and n_chunks > 1 else key)
-            outs.append(self._infer_chunk(x[start:start + take], ck))
+            outs.append(self._infer_chunk(x, start, take, ck))
             start += take
             ci += 1
-        logits = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+        if len(outs) == 1:
+            logits = outs[0]
+        else:
+            with spans.span("engine.slice"):
+                logits = jnp.concatenate(outs)
         if block:
-            logits.block_until_ready()
-        dt = time.perf_counter() - t0
-        with self._lock:
-            self._requests += 1
-            self._images += n
-            if block:
-                self._blocked_images += n
-                self._busy_s += dt
-                self._latencies.append(dt)
-                if len(self._latencies) > _LATENCY_WINDOW:
-                    del self._latencies[:-_LATENCY_WINDOW]
+            with spans.span("engine.device_wait") as wait:
+                logits.block_until_ready()
+            self._local.device_wait_ns = wait.t1 - wait.t0
         return logits
 
     def infer_one(self, image, key: Optional[jax.Array] = None
@@ -347,7 +370,7 @@ class ServingEngine:
     def stats(self) -> dict:
         """Serving metrics + the underlying cache/trace hooks."""
         with self._lock:
-            lat = sorted(self._latencies)
+            infer_s = self._infer_ns * 1e-9
             warm = self._warm
             retraces = self._retraces
             out = {
@@ -359,11 +382,14 @@ class ServingEngine:
                 "padding_fraction": (
                     self._padded_slots / self._executed_slots
                     if self._executed_slots else 0.0),
-                "latency_p50_s": _percentile(lat, 0.50),
-                "latency_p99_s": _percentile(lat, 0.99),
-                "latency_mean_s": (sum(lat) / len(lat)) if lat else 0.0,
-                "sustained_ips": (self._blocked_images / self._busy_s
-                                  if self._busy_s > 0 else 0.0),
+                # Service time of blocking calls, from the call to its
+                # logits: no queue wait (MicroBatcher.stats() has it).
+                "infer_s_total": infer_s,
+                "device_wait_s_total": self._device_wait_ns * 1e-9,
+                "latency_mean_s": (infer_s / self._blocked_requests
+                                   if self._blocked_requests else 0.0),
+                "sustained_ips": (self._blocked_images / infer_s
+                                  if infer_s > 0 else 0.0),
                 "buckets": list(self.buckets),
                 "data_parallel": self.data_parallel,
                 "n_devices": len(self.devices),
@@ -385,6 +411,13 @@ class ServingEngine:
         return out
 
 
+class _Queued(NamedTuple):
+    image: jnp.ndarray
+    fut: Future
+    request: int            # id, in submit order
+    submit_ns: int          # wall clock at the enqueue
+
+
 class MicroBatcher:
     """Thread-safe request coalescer: single images in, bucketed batches
     through a ServingEngine, per-request Futures out.
@@ -399,6 +432,12 @@ class MicroBatcher:
     With a noise-enabled engine pass a root ``key``: each formed batch
     folds in a monotonic counter, so batches draw independent noise and
     a given (key, arrival order) replays exactly.
+
+    ``stats()`` counts, over the batches formed, their fill, each
+    request's wait in the queue (submit to its batch's dispatch) and the
+    time the worker spent on each batch, split into waiting for the
+    device and host work (stacking, the engine's host path, resolving
+    the Futures).
     """
 
     def __init__(self, engine: ServingEngine, max_delay_s: float = 0.002,
@@ -417,12 +456,21 @@ class MicroBatcher:
                 "PRNG key (per-batch keys are folded in)")
         self._key = key
         self._batch_counter = 0
-        self._queue: "queue.Queue[tuple]" = queue.Queue()
+        self._queue: "queue.Queue[_Queued]" = queue.Queue()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._lock = threading.Lock()
+        # Ids are taken with the enqueue under one lock, so the queue
+        # holds them in order and a batch holds consecutive ones.
+        self._submit_lock = threading.Lock()
+        self._request_ids = itertools.count()
+        self._batch_ids = itertools.count()
         self._batches_formed = 0
         self._requests_batched = 0
+        self._queue_wait_ns = 0
+        self._queue_wait_max_ns = 0
+        self._batch_host_ns = 0
+        self._batch_device_wait_ns = 0
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "MicroBatcher":
@@ -463,7 +511,9 @@ class MicroBatcher:
             raise ValueError(f"image must be (H, W, C), got shape "
                              f"{tuple(image.shape)}")
         fut: Future = Future()
-        self._queue.put((image, fut))
+        with self._submit_lock:
+            self._queue.put(_Queued(image, fut, next(self._request_ids),
+                                    time.time_ns()))
         return fut
 
     def _next_key(self):
@@ -486,7 +536,7 @@ class MicroBatcher:
                     break
             if not group:
                 return
-            self._dispatch(group)
+            self._dispatch(group, next(self._batch_ids))
 
     def _run(self) -> None:
         while True:
@@ -497,41 +547,62 @@ class MicroBatcher:
                     self._drain_now()      # requests that raced the stop
                     return
                 continue
+            bid = next(self._batch_ids)
             batch = [first]
-            deadline = time.perf_counter() + self._max_delay_s
-            while len(batch) < self._max_batch:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    batch.append(self._queue.get(timeout=remaining))
-                except queue.Empty:
-                    break
-            self._dispatch(batch)
+            with spans.span("batcher.coalesce", batch=bid):
+                deadline = time.perf_counter() + self._max_delay_s
+                while len(batch) < self._max_batch:
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        batch.append(self._queue.get(timeout=remaining))
+                    except queue.Empty:
+                        break
+            self._dispatch(batch, bid)
 
-    def _dispatch(self, batch: list) -> None:
-        try:
-            # stack is inside the guard: mixed image shapes in one
-            # coalescing window must fail THESE futures, not kill the
-            # worker thread (which would hang every later request).
-            images = jnp.stack([b[0] for b in batch])
-            logits = self._engine.infer(images, key=self._next_key())
-        except Exception as exc:  # surface engine errors per request
-            for _, fut in batch:
-                fut.set_exception(exc)
-            return
-        for i, (_, fut) in enumerate(batch):
-            fut.set_result(logits[i])
+    def _dispatch(self, batch: List[_Queued], bid: int) -> None:
+        with spans.span("batcher.batch", batch=bid,
+                        request=batch[0].request, count=len(batch)) as whole:
+            waits = [whole.t0 - r.submit_ns for r in batch]
+            for r in batch:
+                spans.add("batcher.queue_wait", r.submit_ns, whole.t0,
+                          request=r.request, batch=bid)
+            try:
+                # stack is inside the guard: mixed image shapes in one
+                # coalescing window must fail THESE futures, not kill the
+                # worker thread (which would hang every later request).
+                with spans.span("batcher.stack"):
+                    images = jnp.stack([r.image for r in batch])
+                logits = self._engine.infer(images, key=self._next_key())
+            except Exception as exc:  # surface engine errors per request
+                for r in batch:
+                    r.fut.set_exception(exc)
+                return
+            wait_ns = self._engine._local.device_wait_ns
+            with spans.span("batcher.scatter"):
+                for i, r in enumerate(batch):
+                    r.fut.set_result(logits[i])
         with self._lock:
             self._batches_formed += 1
             self._requests_batched += len(batch)
+            self._queue_wait_ns += sum(waits)
+            self._queue_wait_max_ns = max(self._queue_wait_max_ns,
+                                          max(waits))
+            self._batch_host_ns += whole.t1 - whole.t0 - wait_ns
+            self._batch_device_wait_ns += wait_ns
 
     def stats(self) -> dict:
         with self._lock:
             formed = self._batches_formed
             n = self._requests_batched
+            waits = {"queue_wait_s_total": self._queue_wait_ns * 1e-9,
+                     "queue_wait_s_max": self._queue_wait_max_ns * 1e-9,
+                     "batch_host_s_total": self._batch_host_ns * 1e-9,
+                     "batch_device_wait_s_total":
+                         self._batch_device_wait_ns * 1e-9}
         return {"batches_formed": formed, "requests_batched": n,
-                "mean_fill": (n / formed) if formed else 0.0,
+                "mean_fill": (n / formed) if formed else 0.0, **waits,
                 "max_delay_s": self._max_delay_s,
                 "max_batch": self._max_batch,
                 "queued": self._queue.qsize()}

@@ -208,10 +208,12 @@ def taom_gemm_quantized(xq: jnp.ndarray, wq: jnp.ndarray,
     sigma = detection_sigma(cfg)
 
     if chunk_adc:
+        name = "taom_chunk_adc"
         kern = functools.partial(
             _kernel_chunk_adc, n_chunks=n_chunks, sigma=sigma,
             adc_bits=cfg.adc_bits, fs_chunk=chunk_fs(cfg))
     else:
+        name = "taom_analog_carry"
         kern = functools.partial(
             _kernel_analog_carry, n_chunks=n_chunks, sigma=sigma,
             adc_bits=cfg.adc_bits, adc_fs=adc_fs)
@@ -231,5 +233,6 @@ def taom_gemm_quantized(xq: jnp.ndarray, wq: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_BUDGET_BYTES),
         interpret=interpret,
+        name=name,
     )(x2, wq_c, noise_p)
     return out[:m, :d]

@@ -539,7 +539,10 @@ def graph_forward(params: dict, x: jnp.ndarray, graph: OpGraph,
     the executor plugs the photonic kernel + per-layer plan/noise key in
     here; ``graph_apply`` plugs a plain (or photonic-reference) matmul.
     All shape bookkeeping is static Python, so the walk traces into a
-    single jax.jit program with zero host syncs.
+    single jax.jit program with zero host syncs.  Each node's body runs
+    under ``jax.named_scope(node.name)``, so every device operation of a
+    trace names its node in its ``op_name`` (metadata only: the program
+    and its bits are the same).
     """
     n = x.shape[0]
     vals: Dict[str, jnp.ndarray] = {}
@@ -549,22 +552,23 @@ def graph_forward(params: dict, x: jnp.ndarray, graph: OpGraph,
             vals[node.name] = x
             continue
         a = vals[node.inputs[0]]
-        if node.op in ("conv", "depthwise_conv"):
-            wgt = params[node.name]
-            w2d = (depthwise_block_diag(wgt)
-                   if node.op == "depthwise_conv" else wgt)
-            cols, (oh, ow) = im2col(a, node.kh, node.kw, node.stride,
-                                    node.padding)
-            out = mm(cols.reshape(-1, cols.shape[-1]), w2d, gi, node)
-            y = out.reshape(n, oh, ow, w2d.shape[-1])
-            gi += 1
-        elif node.op == "fc":
-            y = mm(a.reshape(n, -1), params[node.name], gi, node)
-            gi += 1
-        else:
-            y = _apply_glue(node, a, vals)
-        if node.relu:
-            y = jax.nn.relu(y)
+        with jax.named_scope(node.name):
+            if node.op in ("conv", "depthwise_conv"):
+                wgt = params[node.name]
+                w2d = (depthwise_block_diag(wgt)
+                       if node.op == "depthwise_conv" else wgt)
+                cols, (oh, ow) = im2col(a, node.kh, node.kw, node.stride,
+                                        node.padding)
+                out = mm(cols.reshape(-1, cols.shape[-1]), w2d, gi, node)
+                y = out.reshape(n, oh, ow, w2d.shape[-1])
+                gi += 1
+            elif node.op == "fc":
+                y = mm(a.reshape(n, -1), params[node.name], gi, node)
+                gi += 1
+            else:
+                y = _apply_glue(node, a, vals)
+            if node.relu:
+                y = jax.nn.relu(y)
         vals[node.name] = y
     return vals
 

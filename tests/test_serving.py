@@ -140,8 +140,11 @@ class TestBucketedServing:
         s = engine.stats()
         assert s["requests"] >= 1 and s["images"] >= 3
         assert s["padded_slots"] > 0 and 0 < s["padding_fraction"] < 1
-        assert s["latency_p50_s"] <= s["latency_p99_s"]
-        assert s["sustained_ips"] > 0
+        assert 0 < s["device_wait_s_total"] <= s["infer_s_total"]
+        assert s["latency_mean_s"] == pytest.approx(
+            s["infer_s_total"] / s["requests"])
+        assert s["sustained_ips"] == pytest.approx(
+            s["images"] / s["infer_s_total"])
         assert s["warmed_up"] is True
         assert s["plan_cache"]["entries"] > 0
         assert s["compile_cache"]["entries"] > 0
