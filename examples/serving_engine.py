@@ -10,7 +10,8 @@ Demonstrates exec.serving (ISSUE 4) end to end:
    fits and sliced back (zero retraces, bitwise equal to an exact-size
    batch).
 3. Coalesce single-image requests through the thread-safe MicroBatcher
-   (Futures resolve with each request's row of the batched logits).
+   (each batch's logits are read to the host once; Futures resolve
+   with each request's row as a numpy array).
 4. If several devices are visible (e.g. XLA_FLAGS=
    --xla_force_host_platform_device_count=4), serve the same traffic
    data-parallel: the bucketed batch is sharded over the batch axis with
@@ -24,6 +25,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.perf_model import AcceleratorConfig
 from repro.core.types import Backend, Dataflow, PhotonicConfig
@@ -71,7 +73,8 @@ def main():
             jax.random.fold_in(key, 200 + i), (h, w, zoo.in_ch)))
             for i in range(12)]
         outs = [f.result(timeout=60) for f in futs]
-    assert all(o.shape == (zoo.num_classes,) for o in outs)
+    assert all(isinstance(o, np.ndarray) and o.shape == (zoo.num_classes,)
+               for o in outs)
     print(f"== micro-batcher coalesced 12 single-image requests: "
           f"{mb.stats()} ==")
 

@@ -29,8 +29,9 @@ implies:
     after warmup is asserted by benchmarks/serving.py and CI);
 
   * a thread-safe **micro-batcher** — coalesces single-image requests
-    from a queue into bucketed batches under a max-delay knob, resolving
-    each request's Future with its row of the batched logits;
+    from a queue into bucketed batches under a max-delay knob, reading
+    each batch's logits to the host once and resolving each request's
+    Future with its numpy row;
 
   * a **multi-device data-parallel path** — the bucketed batch is placed
     on a NamedSharding over the image batch axis of a 1-D ('data',) mesh
@@ -412,7 +413,7 @@ class ServingEngine:
 
 
 class _Queued(NamedTuple):
-    image: jnp.ndarray
+    image: jnp.ndarray      # (1, H, W, C), on the device
     fut: Future
     request: int            # id, in submit order
     submit_ns: int          # wall clock at the enqueue
@@ -425,9 +426,13 @@ class MicroBatcher:
     A background worker takes the first queued request, then keeps
     gathering until either ``max_batch`` requests are in hand or
     ``max_delay_s`` has elapsed since the first one — the classic
-    latency/throughput knob.  The stacked batch goes through
-    ``engine.infer`` (which pads it to a bucket), and each Future
-    resolves with its own row of the logits.
+    latency/throughput knob.  ``submit`` puts each image on the device
+    as a (1, H, W, C) array, so the worker assembles a batch with one
+    concatenation; the batch goes through ``engine.infer`` (which pads
+    it to a bucket), its logits are read to the host once, and each
+    Future resolves with its own row: a read-only (classes,) numpy
+    array, bitwise the device's logits.  The worker's device work per
+    batch is thus constant, not one operation per request.
 
     With a noise-enabled engine pass a root ``key``: each formed batch
     folds in a monotonic counter, so batches draw independent noise and
@@ -436,8 +441,10 @@ class MicroBatcher:
     ``stats()`` counts, over the batches formed, their fill, each
     request's wait in the queue (submit to its batch's dispatch) and the
     time the worker spent on each batch, split into waiting for the
-    device and host work (stacking, the engine's host path, resolving
-    the Futures).
+    device and host work (assembly, the engine's host path, the read of
+    the logits and resolving the Futures); ``result_reads`` counts the
+    batches resolved from one device-to-host read and
+    ``result_read_s_total`` the time those reads took.
     """
 
     def __init__(self, engine: ServingEngine, max_delay_s: float = 0.002,
@@ -471,6 +478,8 @@ class MicroBatcher:
         self._queue_wait_max_ns = 0
         self._batch_host_ns = 0
         self._batch_device_wait_ns = 0
+        self._result_reads = 0
+        self._result_read_ns = 0
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "MicroBatcher":
@@ -503,13 +512,18 @@ class MicroBatcher:
     # -- request path --------------------------------------------------------
     def submit(self, image) -> "Future":
         """Enqueue one (H, W, C) image; the Future resolves to its
-        (classes,) logits (or raises what the engine raised)."""
+        (classes,) logits as a numpy row (or raises what the engine
+        raised).  A host image is reshaped to (1, H, W, C) on the host
+        and sent to the device here, on the caller's thread."""
         if self._stop.is_set():
             raise RuntimeError("MicroBatcher is stopped")
-        image = jnp.asarray(image)
+        on_device = isinstance(image, jax.Array)
+        if not on_device:
+            image = np.asarray(image)
         if image.ndim != 3:
             raise ValueError(f"image must be (H, W, C), got shape "
                              f"{tuple(image.shape)}")
+        image = image[None] if on_device else jnp.asarray(image[None])
         fut: Future = Future()
         with self._submit_lock:
             self._queue.put(_Queued(image, fut, next(self._request_ids),
@@ -569,20 +583,29 @@ class MicroBatcher:
                 spans.add("batcher.queue_wait", r.submit_ns, whole.t0,
                           request=r.request, batch=bid)
             try:
-                # stack is inside the guard: mixed image shapes in one
-                # coalescing window must fail THESE futures, not kill the
-                # worker thread (which would hang every later request).
+                # Assembly and the read are inside the guard: mixed image
+                # shapes in one coalescing window, or a failed read, must
+                # fail THESE futures, not kill the worker thread (which
+                # would hang every later request).
                 with spans.span("batcher.stack"):
-                    images = jnp.stack([r.image for r in batch])
+                    shapes = {r.image.shape for r in batch}
+                    if len(shapes) > 1:
+                        raise ValueError(
+                            f"images of different shapes in one batch: "
+                            f"{sorted(sh[1:] for sh in shapes)}")
+                    images = jnp.concatenate([r.image for r in batch])
                 logits = self._engine.infer(images, key=self._next_key())
+                with spans.span("batcher.scatter"):
+                    with spans.span("batcher.read") as read:
+                        rows = np.asarray(logits)
+                    for r, row in zip(batch, rows):
+                        r.fut.set_result(row)
             except Exception as exc:  # surface engine errors per request
                 for r in batch:
-                    r.fut.set_exception(exc)
+                    if not r.fut.done():
+                        r.fut.set_exception(exc)
                 return
             wait_ns = self._engine._local.device_wait_ns
-            with spans.span("batcher.scatter"):
-                for i, r in enumerate(batch):
-                    r.fut.set_result(logits[i])
         with self._lock:
             self._batches_formed += 1
             self._requests_batched += len(batch)
@@ -591,6 +614,8 @@ class MicroBatcher:
                                           max(waits))
             self._batch_host_ns += whole.t1 - whole.t0 - wait_ns
             self._batch_device_wait_ns += wait_ns
+            self._result_reads += 1
+            self._result_read_ns += read.t1 - read.t0
 
     def stats(self) -> dict:
         with self._lock:
@@ -600,7 +625,9 @@ class MicroBatcher:
                      "queue_wait_s_max": self._queue_wait_max_ns * 1e-9,
                      "batch_host_s_total": self._batch_host_ns * 1e-9,
                      "batch_device_wait_s_total":
-                         self._batch_device_wait_ns * 1e-9}
+                         self._batch_device_wait_ns * 1e-9,
+                     "result_reads": self._result_reads,
+                     "result_read_s_total": self._result_read_ns * 1e-9}
         return {"batches_formed": formed, "requests_batched": n,
                 "mean_fill": (n / formed) if formed else 0.0, **waits,
                 "max_delay_s": self._max_delay_s,

@@ -273,11 +273,33 @@ class TestMicroBatcher:
         mb.stop()
         ref = engine.infer(jnp.stack(imgs))
         for i, out in enumerate(outs):
-            np.testing.assert_array_equal(np.asarray(out),
-                                          np.asarray(ref[i]))
+            assert isinstance(out, np.ndarray)
+            np.testing.assert_array_equal(out, np.asarray(ref[i]))
         s = mb.stats()
         assert s["batches_formed"] == 1 and s["requests_batched"] == 8
         assert s["mean_fill"] == 8.0
+        assert s["result_reads"] == s["batches_formed"]
+        assert s["result_read_s_total"] > 0
+
+    def test_host_and_device_images_give_bitwise_rows(self, served):
+        """The same images sent as numpy arrays and as jax Arrays form
+        batches whose rows are bitwise equal, and equal to the engine
+        run on the stacked batch; every batch is resolved by one read."""
+        key, _, engine = served
+        imgs = [_images(key, 720 + i, 1)[0] for i in range(3)]
+        mb = MicroBatcher(engine, max_delay_s=0.05, max_batch=3)
+        futs = ([mb.submit(np.asarray(im)) for im in imgs]
+                + [mb.submit(im) for im in imgs])
+        mb.start()
+        outs = [f.result(timeout=120) for f in futs]
+        mb.stop()
+        ref = np.asarray(engine.infer(jnp.stack(imgs)))
+        for i in range(3):
+            np.testing.assert_array_equal(outs[i], outs[i + 3])
+            np.testing.assert_array_equal(outs[i], ref[i])
+        s = mb.stats()
+        assert s["batches_formed"] == 2 and s["mean_fill"] == 3.0
+        assert s["result_reads"] == s["batches_formed"]
 
     def test_concurrent_submitters_all_resolve(self, served):
         key, _, engine = served

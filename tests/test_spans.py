@@ -91,6 +91,8 @@ def test_recording_off_stores_nothing_and_counters_count(engine):
     assert st["queue_wait_s_total"] >= st["queue_wait_s_max"] > 0
     assert st["batch_host_s_total"] > 0
     assert st["batch_device_wait_s_total"] > 0
+    assert st["result_reads"] == st["batches_formed"]
+    assert st["result_read_s_total"] > 0
     after = engine.stats()
     assert after["infer_s_total"] > before["infer_s_total"]
     assert after["device_wait_s_total"] > before["device_wait_s_total"]
@@ -133,9 +135,10 @@ def test_micro_batcher_spans_nest_and_match_counters(engine, recording):
         kids = children(b)
         assert [k.name for k in kids] == ["batcher.stack", "engine.infer",
                                           "batcher.scatter"]
-        infer = kids[1]
+        infer, scatter = kids[1], kids[2]
         assert [k.name for k in children(infer)] == ENGINE_CHILDREN
-        for k in kids + children(infer):
+        assert [k.name for k in children(scatter)] == ["batcher.read"]
+        for k in kids + children(infer) + children(scatter):
             assert k.thread == "micro-batcher"
             parent = by_id[k.parent]
             assert parent.t0_ns <= k.t0_ns <= k.t1_ns <= parent.t1_ns
@@ -150,6 +153,10 @@ def test_micro_batcher_spans_nest_and_match_counters(engine, recording):
     assert st["batch_host_s_total"] == pytest.approx(host * 1e-9, rel=1e-12)
     assert st["batch_device_wait_s_total"] == pytest.approx(wait * 1e-9,
                                                             rel=1e-12)
+    reads = named("batcher.read")
+    assert len(reads) == st["result_reads"] == st["batches_formed"]
+    assert st["result_read_s_total"] == pytest.approx(
+        sum(_dur(r) for r in reads) * 1e-9, rel=1e-12)
     infers = named("engine.infer")
     assert after["infer_s_total"] - before["infer_s_total"] == \
         pytest.approx(sum(_dur(s) for s in infers) * 1e-9, rel=1e-9)
