@@ -32,9 +32,10 @@ depthwise counts) into per-layer ``EnergyBreakdown``s and whole-network
 FPS / FPS/W — charged by the SAME ``core.perf_model.gemm_cost`` event
 accounting the analytic figures use, plus the static-power share over the
 executed wall-clock.  Depthwise layers are charged on the paper's grouped
-accounting (count x (C, k*k, 1) GEMMs): the executor's fused
-block-diagonal GEMM is a host-simulation device, not extra photonic work
-(the fused matrix is mostly structural zeros).
+accounting (count x (C, k*k, 1) GEMMs): the executor runs them grouped
+under analog carry, and under chunk ADC as a fused block-diagonal GEMM,
+a host-simulation device, not extra photonic work (the fused matrix is
+mostly structural zeros).
 """
 from __future__ import annotations
 

@@ -18,7 +18,7 @@ agree exactly when noise is disabled.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -26,15 +26,19 @@ from repro.core.types import TAOM_MAX_PULSE_WIDTH_NS, PhotonicConfig
 
 
 def quantize(x: jnp.ndarray, bits: int, axis=None, keepdims: bool = True,
-             eps: float = 1e-12) -> Tuple[jnp.ndarray, jnp.ndarray]:
+             eps: float = 1e-12, absmax: Optional[jnp.ndarray] = None
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Symmetric B-bit quantization: returns integer-valued q and scale s.
 
     x ~= q * s with q in [-qmax, qmax].  ``axis=None`` => per-tensor scale;
     an int/tuple axis gives per-channel scales (reduced over ``axis``).
+    ``absmax`` replaces the max of |x| when the scale belongs to fewer
+    values than ``x`` holds (the pixels a strided window samples).
     """
     qmax = (1 << bits) - 1
-    absmax = jnp.max(jnp.abs(x), axis=axis,
-                     keepdims=(axis is not None) and keepdims)
+    if absmax is None:
+        absmax = jnp.max(jnp.abs(x), axis=axis,
+                         keepdims=(axis is not None) and keepdims)
     # Explicit reciprocal multiply, NOT division by the constant qmax: XLA
     # rewrites x/const into x*(1/const) when jit-compiling whole programs
     # but not op-by-op, so a division here would make jitted and eager

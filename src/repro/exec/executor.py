@@ -48,7 +48,7 @@ import dataclasses
 import functools
 import threading
 from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Sequence, Union
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +60,7 @@ from repro.core.types import PhotonicConfig
 from repro.exec import plan_cache as pc
 from repro.exec.scheduler import CnnPlan, LayerPlan
 from repro.kernels import ops
+from repro.kernels.taom_gemm import LANE, _round_up
 from repro.models import cnn as cnn_mod
 from repro.models import lowering as lw
 
@@ -118,14 +119,15 @@ class ExecutionResult:
             acc = self.plan.acc
             self._traces = []
             for i, p in enumerate(self.plan.layers):
-                # "what actually ran": depthwise layers execute as ONE
-                # fused block-diagonal GEMM, so trace the executed
-                # (M, K, D) — LayerGemm.executed owns the convention —
-                # consistent with the tile the scheduler sized for it.
+                # "what actually ran": depthwise layers execute grouped
+                # or as ONE fused block-diagonal GEMM, by policy, so
+                # trace the executed (M, K, D) — LayerGemm.executed owns
+                # the convention — consistent with the tile the
+                # scheduler sized for it.
                 m, k, d = lw.LayerGemm(p.name, p.c, p.k, p.d,
-                                       p.count).executed
+                                       p.count).executed(acc.backend)
                 # Hardware event counts behind the executed energy: ADCs
-                # are charged on the paper's grouped accounting (the
+                # are charged on the paper's grouped accounting (a
                 # fused depthwise GEMM is a host-simulation device, its
                 # structural zeros are not photonic work).
                 sch = df.schedule(df.GemmShape(p.c, p.k, p.d), p.dataflow,
@@ -194,6 +196,37 @@ def _layer_matmul(cols: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
                                block_d=plan.tile.block_d, mesh=mesh)
 
 
+def _layer_depthwise(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
+                     key: Optional[jax.Array], plan: LayerPlan,
+                     node: lw.OpNode, impl: str,
+                     mesh: Optional[Mesh]) -> jnp.ndarray:
+    return ops.photonic_depthwise(x, w, cfg, key=key, impl=impl,
+                                  kh=node.kh, kw=node.kw,
+                                  stride=node.stride, padding=node.padding,
+                                  block_m=plan.tile.block_m,
+                                  block_d=plan.tile.block_d, mesh=mesh)
+
+
+def executed_macs(plan: CnnPlan) -> Tuple[int, int]:
+    """(all layers, depthwise layers): the MACs one call of ``plan``'s
+    program performs as laid out.  A TAOM GEMM kernel runs K in
+    ceil(K/N) chunk slots of 128 lanes, block-diagonal zeros included;
+    a grouped depthwise layer reads kh*kw taps per output.  Rows and
+    output channels are the layer's own (tile padding is not counted)."""
+    slot = _round_up(plan.acc.n, LANE)
+    total = depthwise = 0
+    for p in plan.layers:
+        g = lw.LayerGemm(p.name, p.c, p.k, p.d, p.count)
+        m, k, d = g.executed(plan.acc.backend)
+        if g.depthwise and lw.grouped_depthwise(plan.acc.backend):
+            macs = m * k * d
+        else:
+            macs = m * p.tile.n_chunks * slot * d
+        total += macs
+        depthwise += macs if g.depthwise else 0
+    return total, depthwise
+
+
 # ---------------------------------------------------------------------------
 # Pure forward (the jit-compiled hot path)
 # ---------------------------------------------------------------------------
@@ -222,10 +255,12 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     Walks the lowering's op graph (models.lowering.graph_forward): every
     GEMM-bearing node (conv / depthwise_conv / fc) runs through the
     photonic kernel with its LayerPlan's tiling and an independent noise
-    key; glue nodes (pool / residual_add / concat / shuffle / slice) are
-    plain jnp ops.  Everything after the array arguments is static
-    configuration; no host sync happens anywhere in the body
-    (fingerprints stay device arrays).  Fingerprints are per GEMM node,
+    key — depthwise nodes of an analog-carry policy through the grouped
+    depthwise kernel (ops.photonic_depthwise); glue nodes (pool /
+    residual_add / concat / shuffle / slice) are plain jnp ops.
+    Everything after the array arguments is static configuration; no
+    host sync happens anywhere in the body (fingerprints stay device
+    arrays).  Fingerprints are per GEMM node,
     taken right after its activation (before any downstream glue).
     ``mesh``: the batch of ``x`` is sharded over it (data-parallel
     serving); each GEMM kernel then runs per device on its rows.
@@ -235,14 +270,22 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
         _TRACE_COUNT += 1
     graph = cnn_mod.as_graph(lowering, plan=plan)
 
+    def layer_key(gi: int) -> Optional[jax.Array]:
+        return (jax.random.fold_in(key, gi)
+                if key is not None and cfg.noise_enabled else None)
+
     def mm(a2d: jnp.ndarray, w2d: jnp.ndarray, gi: int,
            node: lw.OpNode) -> jnp.ndarray:
-        layer_key = (jax.random.fold_in(key, gi)
-                     if key is not None and cfg.noise_enabled else None)
-        return _layer_matmul(a2d, w2d, cfg, layer_key, plan.layers[gi],
+        return _layer_matmul(a2d, w2d, cfg, layer_key(gi), plan.layers[gi],
                              impl, mesh)
 
-    vals = lw.graph_forward(params, x, graph, mm)
+    def dw(a: jnp.ndarray, w: jnp.ndarray, gi: int,
+           node: lw.OpNode) -> jnp.ndarray:
+        return _layer_depthwise(a, w, cfg, layer_key(gi), plan.layers[gi],
+                                node, impl, mesh)
+
+    vals = lw.graph_forward(params, x, graph, mm,
+                            dw if lw.grouped_depthwise(cfg.backend) else None)
     gemm_outs = [vals[n.name] for n in graph.gemm_nodes]
     # mean |activation| via explicit reciprocal multiply — jnp.mean's
     # division by the (constant) element count is reassociated by XLA

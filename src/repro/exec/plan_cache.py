@@ -41,8 +41,9 @@ from typing import Dict, Optional
 # skips foreign-version entries instead of admitting plans whose layout
 # or semantics have since changed.  v4: plans embed the hardware
 # operating point (repro.core.hw.OperatingPoint) — pre-v4 entries carry
-# no version stamp at all and are likewise skipped.
-PLAN_FORMAT_VERSION = 4
+# no version stamp at all and are likewise skipped.  v5: analog-carry
+# depthwise layers plan the grouped depthwise kernel's tile.
+PLAN_FORMAT_VERSION = 5
 
 # Keys every serialized LayerPlan dict must carry to be admitted by
 # ``load`` (mirrors scheduler._plan_to_dict's output).

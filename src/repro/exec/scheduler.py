@@ -48,6 +48,8 @@ from repro.kernels.taom_gemm import LANE as _LANE
 from repro.kernels.taom_gemm import SUBLANE as _SUBLANE
 from repro.kernels.taom_gemm import VMEM_BUDGET_BYTES as _VMEM_BUDGET
 from repro.kernels.taom_gemm import _round_up, vmem_bytes
+from repro.kernels import taom_depthwise as _dw
+from repro.models import lowering as lw
 from repro.models.cnn import LayerGemm
 
 # Large-M tiles cut grid steps: a batch-256 conv (M = 65536 rows) at
@@ -68,7 +70,11 @@ _BLOCK_D_CANDIDATES = (128, 256)
 # CnnPlan carries it for the executor's kernel-cfg coherence check, and
 # persisted entries are stamped with the format version so pre-v4 dumps
 # cleanly invalidate on load (plan_cache.PLAN_FORMAT_VERSION).
-_PLAN_VERSION = pc.PLAN_FORMAT_VERSION
+# v5: depthwise layers of analog-carry policies run grouped
+# (kernels.taom_depthwise) and plan that kernel's tile.  Only those
+# layers key on v5, so every other layer keeps its v4 key and its plan.
+_PLAN_VERSION = 4
+_DEPTHWISE_PLAN_VERSION = 5
 
 #: What the scheduling entry points accept as "the hardware": a bare
 #: AcceleratorConfig (legacy) or a full OperatingPoint (preferred — the
@@ -236,9 +242,28 @@ def choose_tile(m: int, d: int, k: int, dpe_size: int) -> TileChoice:
     return best[1]
 
 
+def choose_depthwise_tile(m: int, c: int, k: int,
+                          dpe_size: int) -> TileChoice:
+    """The grouped depthwise kernel's tile for M output pixels of C
+    channels: ``block_d`` the channel block with the least lane padding
+    (kernels.taom_depthwise.choose_block_c; the kernel narrows it to one
+    lane block at a stride above 1), ``block_m`` the pixels a grid step
+    may hold, which the kernel fits to its VMEM budget in whole rows or
+    images (``taom_depthwise.step_shape``: only the kernel sees the
+    layer's width and stride).  ``n_chunks`` is the temporal
+    folds of the layer's block-diagonal K = k*C, whose count sets the
+    noise scale."""
+    bd = _dw.choose_block_c(c)
+    bm = min(_dw.BLOCK_M_DEFAULT, _round_up(m, _SUBLANE))
+    cp = _round_up(c, bd)
+    return TileChoice(bm, bd, -(-m // bm), cp // bd,
+                      max(1, -(-(k * c) // dpe_size)), cp / float(c) - 1.0)
+
+
 def _cache_payload(g: df.GemmShape, count: int, acc: pm.AcceleratorConfig,
-                   objective: str, flows: Sequence[Dataflow]) -> dict:
-    return {
+                   objective: str, flows: Sequence[Dataflow],
+                   grouped: bool = False) -> dict:
+    payload = {
         "v": _PLAN_VERSION,
         "gemm": [g.c, g.k, g.d],
         "count": count,
@@ -248,6 +273,10 @@ def _cache_payload(g: df.GemmShape, count: int, acc: pm.AcceleratorConfig,
         "tiles": [_BLOCK_M_CANDIDATES, _BLOCK_D_CANDIDATES],
         "vmem_budget": _VMEM_BUDGET,
     }
+    if grouped:
+        payload.update(v=_DEPTHWISE_PLAN_VERSION, depthwise="grouped",
+                       tiles=[_dw.BLOCK_M_DEFAULT, _dw.BLOCK_C_CANDIDATES])
+    return payload
 
 
 def _plan_to_dict(p: LayerPlan) -> dict:
@@ -255,7 +284,7 @@ def _plan_to_dict(p: LayerPlan) -> dict:
     d["dataflow"] = p.dataflow.value
     d.pop("name")          # content-addressed: names don't enter the cache
     d.pop("cache_hit")
-    d["plan_version"] = _PLAN_VERSION   # load-time invalidation stamp
+    d["plan_version"] = pc.PLAN_FORMAT_VERSION  # load-time invalidation
     return d
 
 
@@ -276,20 +305,22 @@ def plan_layer(layer: LayerGemm, acc: HardwareSpec, batch: int = 1,
     acc, _ = _resolve_hw(acc)
     cache = cache if cache is not None else pc.GLOBAL_PLAN_CACHE
     g = df.GemmShape(layer.c * batch, layer.k, layer.d)
+    grouped = layer.depthwise and lw.grouped_depthwise(acc.backend)
     key = pc.fingerprint(_cache_payload(g, layer.count, acc, objective,
-                                        flows))
+                                        flows, grouped))
     cached = cache.get(key)
     if cached is not None:
         return _plan_from_dict(cached, layer.name, cache_hit=True)
 
     flow, cost, costs = pm.best_dataflow(g, acc, flows, objective)
     # Dataflow cost is charged on the paper's analytic shape (count
-    # grouped instances), but the tile must fit the GEMM the executor
-    # actually runs — LayerGemm.executed owns that fusion convention
-    # (depthwise groups fuse into one block-diagonal GEMM).
+    # grouped instances), but the tile must fit what the executor
+    # actually runs — LayerGemm.executed owns that convention (grouped
+    # depthwise taps, or one block-diagonal GEMM).
     em, ek, ed = LayerGemm(layer.name, g.c, g.k, g.d,
-                           layer.count).executed
-    tile = choose_tile(em, ed, ek, acc.n)
+                           layer.count).executed(acc.backend)
+    tile = (choose_depthwise_tile(em, ed, ek, acc.n) if grouped
+            else choose_tile(em, ed, ek, acc.n))
     plan = LayerPlan(
         name=layer.name, c=g.c, k=g.k, d=g.d, count=layer.count,
         dataflow=flow,

@@ -47,7 +47,8 @@ implies:
   * **serving metrics** — always-on counters (mean service time and
     sustained throughput from the total time inside ``infer``, the time
     spent waiting for the device, the micro-batcher's queue wait and its
-    host time per batch), padding-overhead fraction, the plan/compile
+    host time per batch, the MACs the dispatched programs performed as
+    laid out), padding-overhead fraction, the plan/compile
     cache stats surfaced from the existing ``stats()`` hooks, and the
     photonic model's energy accounting of the served stream (modeled
     joules per inference — padding included, that's the cost of
@@ -219,6 +220,12 @@ class ServingEngine:
         # in full (the padding overhead is visible in j_per_image).
         self._bucket_energy = {b: hw.trace_energy(self.plans[b])
                                for b in self.buckets}
+        # MACs each bucket's program performs as laid out (all layers,
+        # depthwise layers), padding images included.
+        self._bucket_macs = {b: ex.executed_macs(self.plans[b])
+                             for b in self.buckets}
+        self._executed_macs = 0
+        self._depthwise_macs = 0
         self._energy_j = 0.0
         self._model_time_s = 0.0
 
@@ -265,8 +272,11 @@ class ServingEngine:
         with spans.span("engine.dispatch"):
             logits = self._run_bucket(xb, key, bucket)
         te = self._bucket_energy[bucket]
+        macs, dw_macs = self._bucket_macs[bucket]
         with self._lock:
             self._batches += 1
+            self._executed_macs += macs
+            self._depthwise_macs += dw_macs
             self._padded_slots += pad
             self._executed_slots += bucket
             self._energy_j += te.energy_j
@@ -391,6 +401,11 @@ class ServingEngine:
                                    if self._blocked_requests else 0.0),
                 "sustained_ips": (self._blocked_images / infer_s
                                   if infer_s > 0 else 0.0),
+                # Work the dispatched programs performed as laid out
+                # (executor.executed_macs: chunk slots and block-
+                # diagonal zeros included), and its depthwise part.
+                "executed_macs_total": self._executed_macs,
+                "depthwise_executed_macs_total": self._depthwise_macs,
                 "buckets": list(self.buckets),
                 "data_parallel": self.data_parallel,
                 "n_devices": len(self.devices),

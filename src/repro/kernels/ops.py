@@ -3,7 +3,8 @@
 ``photonic_matmul`` is what the model zoo calls: it quantizes, picks the
 kernel or the pure-jnp oracle (kernels run in interpret mode on CPU), and
 attaches the straight-through-estimator VJP so photonic numerics are
-trainable.
+trainable.  ``photonic_depthwise`` does the same for a depthwise layer
+under an analog-carry policy, bitwise its block-diagonal GEMM.
 
 ``ssd_scan`` is the Mamba2 scan entry point: the Pallas kernel for the
 serving/prefill hot path, and a differentiable chunked jnp implementation
@@ -18,12 +19,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.photonic_gemm import sample_noise, noise_shape
+from repro.core.photonic_gemm import (ANALOG_CARRY_BACKENDS, noise_shape,
+                                     sample_noise)
 from repro.core.taom import quantize
 from repro.core.types import Backend, PhotonicConfig
 from repro.kernels import ref as ref_mod
 from repro.kernels import ssd_scan as ssd_kernel_mod
+from repro.kernels import taom_depthwise as taom_dw_kernel_mod
 from repro.kernels import taom_gemm as taom_kernel_mod
+from repro.models.lowering import conv_out_dim, pad_window
 
 
 def _on_cpu() -> bool:
@@ -142,6 +146,134 @@ def photonic_matmul(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
     out = _taom_ste(x2d, w, noise, cfg, float(adc_fs), impl,
                     (int(block_m), int(block_d)), mesh)
     return out.reshape(*batch_shape, w.shape[-1])
+
+
+# ---------------------------------------------------------------------------
+# photonic_depthwise
+# ---------------------------------------------------------------------------
+def _sampled_absmax(xp: jnp.ndarray, kh: int, kw: int, stride: int,
+                    oh: int, ow: int) -> jnp.ndarray:
+    """max |x| over the padded pixels the layer's windows read: the
+    values its block-diagonal GEMM's im2col holds.  With the stride at
+    most the window, they are one rectangle from the top-left corner."""
+    s = stride
+    if s <= kh and s <= kw:
+        return jnp.max(jnp.abs(xp[:, :(oh - 1) * s + kh, :(ow - 1) * s + kw]))
+    return jnp.max(jnp.stack([
+        jnp.max(jnp.abs(xp[:, i:i + (oh - 1) * s + 1:s,
+                           j:j + (ow - 1) * s + 1:s]))
+        for i in range(kh) for j in range(kw)]))
+
+
+def _depthwise_forward(x, w, noise, cfg: PhotonicConfig, adc_fs: float,
+                       impl: str, geometry: tuple, blocks: tuple,
+                       mesh: Optional[Mesh]) -> jnp.ndarray:
+    kh, kw, stride, padding = geometry
+    f32 = jnp.float32
+    xp, (oh, ow) = pad_window(x.astype(f32), kh, kw, stride, padding)
+    xq, sx = quantize(xp, cfg.bits, absmax=_sampled_absmax(
+        xp, kh, kw, stride, oh, ow))
+    wq, sw = quantize(w.astype(f32), cfg.bits, axis=0)
+    operands = (xq, wq) if noise is None else (xq, wq, noise)
+    if impl == "pallas":
+        kernel = functools.partial(
+            taom_dw_kernel_mod.taom_depthwise_quantized, cfg=cfg,
+            adc_fs=adc_fs, kh=kh, kw=kw, stride=stride, block_m=blocks[0],
+            block_d=blocks[1], interpret=_on_cpu())
+        if mesh is not None:
+            # Images are independent: each device runs the kernel on its
+            # own; the quantize max above stays global.
+            images = P(mesh.axis_names)
+            kernel = jax.shard_map(
+                kernel, mesh=mesh,
+                in_specs=(images, P(), images)[:len(operands)],
+                out_specs=images, check_vma=False)
+        acc = kernel(*operands)
+    else:
+        acc = ref_mod.taom_depthwise_reference(
+            *operands, cfg=cfg, adc_fs=adc_fs, kh=kh, kw=kw, stride=stride)
+    # The same barriers and rescale as _taom_forward: the block-diagonal
+    # GEMM of this layer gives these bits.
+    acc, sx, sw = jax.lax.optimization_barrier((acc, sx, sw))
+    out = (acc * (sx * sw)).astype(x.dtype)
+    return jax.lax.optimization_barrier(out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _depthwise_ste(x, w, noise, cfg, adc_fs, impl, geometry, blocks, mesh):
+    return _depthwise_forward(x, w, noise, cfg, adc_fs, impl, geometry,
+                              blocks, mesh)
+
+
+def _depthwise_ste_fwd(x, w, noise, cfg, adc_fs, impl, geometry, blocks,
+                       mesh):
+    return (_depthwise_forward(x, w, noise, cfg, adc_fs, impl, geometry,
+                               blocks, mesh), (x, w))
+
+
+def _depthwise_ste_bwd(cfg, adc_fs, impl, geometry, blocks, mesh, res, g):
+    x, w = res
+    kh, kw, stride, padding = geometry
+
+    def exact(x, w):
+        return jax.lax.conv_general_dilated(
+            x, w.reshape(kh, kw, 1, w.shape[-1]), (stride, stride),
+            padding.upper(), dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            feature_group_count=x.shape[-1])
+
+    dx, dw = jax.vjp(exact, x, w)[1](g.astype(x.dtype))
+    return dx, dw, None
+
+
+_depthwise_ste.defvjp(_depthwise_ste_fwd, _depthwise_ste_bwd)
+
+
+def photonic_depthwise(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
+                       key: Optional[jax.Array] = None, impl: str = "auto",
+                       *, kh: int, kw: int,
+                       stride: int = 1, padding: str = "same",
+                       block_m: int = taom_dw_kernel_mod.BLOCK_M_DEFAULT,
+                       block_d: int = 128,
+                       mesh: Optional[Mesh] = None) -> jnp.ndarray:
+    """Photonic-numerics depthwise conv: (B, H, W, C) images with a
+    (kh*kw, C) tap-major weight -> (B, OH, OW, C), for the analog-carry
+    policies (HEANA, ``*_bpca``).
+
+    Bitwise what ``photonic_matmul`` gives on the layer's im2col and
+    block-diagonal (kh*kw*C, C) weight, without building either: the
+    activation scale is the max over the pixels the windows read, the
+    weight scales are per channel, the noise is drawn with the block-
+    diagonal GEMM's shapes (the same numbers for the same key), the ADC
+    full scale is ``calibrated_adc_fs(kh*kw*C)``, and the rescale is the
+    same.  Gradients are those of the exact depthwise conv (straight-
+    through).  ``block_m``/``block_d``: output pixels per grid step (an
+    upper bound) and the channel block; ``mesh``: the images of ``x``
+    are sharded over it.
+    """
+    if cfg.backend not in ANALOG_CARRY_BACKENDS:
+        raise ValueError(
+            f"photonic_depthwise: {cfg.backend.value!r} reads every DPE "
+            f"chunk through its own ADC, so its depthwise result depends "
+            f"on the block-diagonal K layout — run it through "
+            f"photonic_matmul")
+    if cfg.noise_enabled and key is None:
+        raise ValueError(
+            "photonic_depthwise: cfg.noise_enabled=True but key=None — "
+            "pass a PRNG key, or set noise_enabled=False")
+    if impl == "auto":
+        impl = "pallas"
+    b, h, wd, c = x.shape
+    kk = kh * kw
+    adc_fs = taom_kernel_mod.calibrated_adc_fs(kk * c, cfg)
+    noise = None
+    if key is not None and cfg.noise_enabled:
+        oh = conv_out_dim(h, kh, stride, padding)
+        ow = conv_out_dim(wd, kw, stride, padding)
+        noise = sample_noise(key, (b * oh * ow, kk * c), (kk * c, c),
+                             cfg).reshape(b, oh, ow, c)
+    return _depthwise_ste(x, w, noise, cfg, float(adc_fs), impl,
+                          (int(kh), int(kw), int(stride), str(padding)),
+                          (int(block_m), int(block_d)), mesh)
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ comparisons are apples-to-apples.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax.numpy as jnp
 
@@ -19,6 +20,7 @@ from repro.core.types import Backend, PhotonicConfig
 # the oracle cannot diverge from the kernel by a compile-mode ULP (see
 # adc_round's docstring).
 from repro.kernels.taom_gemm import adc_code, adc_round, adc_step, chunk_fs
+from repro.kernels.taom_depthwise import out_dims
 
 
 def taom_gemm_reference(xq: jnp.ndarray, wq: jnp.ndarray,
@@ -49,6 +51,36 @@ def taom_gemm_reference(xq: jnp.ndarray, wq: jnp.ndarray,
     assert noise.shape == (m, d)
     acc = jnp.sum(psums, axis=0)
     acc = acc + sigma * math.sqrt(float(n_chunks)) * noise
+    return adc_round(acc, cfg.adc_bits, float(adc_fs))
+
+
+def taom_depthwise_reference(xp: jnp.ndarray, wq: jnp.ndarray,
+                             noise: Optional[jnp.ndarray] = None, *,
+                             cfg: PhotonicConfig, adc_fs: float,
+                             kh: int, kw: int, stride: int = 1
+                             ) -> jnp.ndarray:
+    """Oracle for kernels.taom_depthwise.taom_depthwise_quantized.
+
+    Each output is the sum of its kh*kw strided tap products, from zero
+    (exact integers, so any order gives the same sum); the noise and the
+    ADC read are those of the block-diagonal GEMM's analog-carry readout
+    (K = kh*kw*C chunked at N).
+    """
+    b, hp, wp, c = xp.shape
+    s = stride
+    oh, ow = out_dims(hp, wp, kh, kw, s)
+    x = xp.astype(jnp.float32)
+    w = wq.astype(jnp.float32)
+    acc = jnp.zeros((b, oh, ow, c), jnp.float32)
+    for i in range(kh):
+        for j in range(kw):
+            acc = acc + x[:, i:i + (oh - 1) * s + 1:s,
+                          j:j + (ow - 1) * s + 1:s, :] * w[i * kw + j]
+    if noise is not None:
+        assert noise.shape == (b, oh, ow, c), noise.shape
+        n_chunks = max(1, -(-(kh * kw * c) // cfg.dpe_size))
+        acc = acc + (detection_sigma(cfg) * math.sqrt(float(n_chunks))) \
+            * noise
     return adc_round(acc, cfg.adc_bits, float(adc_fs))
 
 

@@ -20,10 +20,12 @@ The IR is a flat topologically-ordered tuple of ``OpNode``s (an
   ``input``           the graph input (carries C_in in ``cout``)
   ``conv``            kh x kw conv, stride/padding, -> im2col GEMM
                       with K = kh*kw*C_in, D = cout
-  ``depthwise_conv``  per-channel kh x kw conv -> ONE block-diagonal
-                      GEMM (K = kh*kw*C, D = C); accounted analytically
+  ``depthwise_conv``  per-channel kh x kw conv, accounted analytically
                       as ``count=C`` grouped (kh*kw, 1) GEMMs, matching
-                      the paper's depthwise tables
+                      the paper's depthwise tables; executed as those
+                      grouped taps under an analog-carry policy and as
+                      ONE block-diagonal GEMM (K = kh*kw*C, D = C) under
+                      a chunk-ADC one (``grouped_depthwise``)
   ``pool``            max / avg / global — glue, no GEMM
   ``residual_add``    elementwise sum of two same-shape producers
   ``concat``          channel concat of >= 2 producers
@@ -43,6 +45,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.photonic_gemm import ANALOG_CARRY_BACKENDS
+
 GEMM_OPS = ("conv", "depthwise_conv", "fc")
 GLUE_OPS = ("pool", "residual_add", "concat", "shuffle", "slice")
 OPS = ("input",) + GEMM_OPS + GLUE_OPS
@@ -53,6 +57,16 @@ PADDINGS = ("same", "valid")
 # ---------------------------------------------------------------------------
 # Analytic GEMM record (the scheduler/perf-model currency)
 # ---------------------------------------------------------------------------
+def grouped_depthwise(backend) -> bool:
+    """Do ``backend``'s depthwise layers run as grouped kh*kw-tap reads
+    (kernels.taom_depthwise)?  Under analog carry each output is one
+    noise draw and one ADC read of an exact integer sum, so the chunk
+    layout of K cannot change it.  Chunk-ADC policies (AMW, MAW) keep
+    the block-diagonal GEMM: their per-chunk noise and reads depend on
+    how K is laid out."""
+    return backend in ANALOG_CARRY_BACKENDS
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerGemm:
     """One layer as an im2col GEMM: I (C x K) @ W (K x D), ``count``
@@ -68,18 +82,26 @@ class LayerGemm:
         return self.c * self.k * self.d * self.count
 
     @property
-    def executed(self) -> Tuple[int, int, int]:
-        """The (M, K, D) of the ONE GEMM the executor actually runs.
+    def depthwise(self) -> bool:
+        """What graph_gemms emits for ``depthwise_conv`` nodes."""
+        return self.count > 1 and self.d == 1
 
-        This is the single home of the fusion convention: depthwise
-        layers (count > 1, d == 1 — what graph_gemms emits for
-        ``depthwise_conv`` nodes) are executed as one block-diagonal
-        GEMM (depthwise_block_diag), so K and D scale by count; every
-        other layer executes its analytic shape as-is.  The scheduler
-        sizes kernel tiles and the executor reports traces against
-        THESE dims — do not re-derive the convention elsewhere.
+    def executed(self, backend) -> Tuple[int, int, int]:
+        """The (M, K, D) the executor actually runs under ``backend``.
+
+        This is the single home of the fusion convention.  Depthwise
+        layers run grouped under an analog-carry policy
+        (``grouped_depthwise``): (M, kh*kw, C), each of the C outputs
+        reading its own kh*kw taps.  Under a chunk-ADC policy they run
+        as one block-diagonal GEMM (depthwise_block_diag), so K and D
+        scale by count.  Every other layer executes its analytic shape
+        as-is.  The scheduler sizes kernel tiles and the executor
+        reports traces against THESE dims — do not re-derive the
+        convention elsewhere.
         """
-        if self.count > 1 and self.d == 1:
+        if self.depthwise:
+            if grouped_depthwise(backend):
+                return (self.c, self.k, self.count)
             return (self.c, self.k * self.count, self.count)
         return (self.c, self.k, self.d)
 
@@ -375,13 +397,10 @@ def infer_shapes(graph: OpGraph, in_hw,
 # im2col (general stride/padding; the stride-1 'same' case is bit-
 # identical to the original models.cnn._im2col)
 # ---------------------------------------------------------------------------
-def im2col(x: jnp.ndarray, kh: int, kw: int, stride: int = 1,
-           padding: str = "same") -> Tuple[jnp.ndarray, Tuple[int, int]]:
-    """NHWC -> ((N, OH*OW, kh*kw*C) patches, (OH, OW)).
-
-    K is ordered patch-position-major, channel-minor — the same layout
-    ``weight_hwio`` expects and build_* initializers produce.
-    """
+def pad_window(x: jnp.ndarray, kh: int, kw: int, stride: int,
+               padding: str) -> Tuple[jnp.ndarray, Tuple[int, int]]:
+    """NHWC -> (the input zero-padded for a kh x kw window, (OH, OW)):
+    TF/XLA SAME splits the padding with the odd pixel at the end."""
     n, h, w, c = x.shape
     oh = conv_out_dim(h, kh, stride, padding)
     ow = conv_out_dim(w, kw, stride, padding)
@@ -390,6 +409,18 @@ def im2col(x: jnp.ndarray, kh: int, kw: int, stride: int = 1,
         pw = max((ow - 1) * stride + kw - w, 0)
         x = jnp.pad(x, ((0, 0), (ph // 2, ph - ph // 2),
                         (pw // 2, pw - pw // 2), (0, 0)))
+    return x, (oh, ow)
+
+
+def im2col(x: jnp.ndarray, kh: int, kw: int, stride: int = 1,
+           padding: str = "same") -> Tuple[jnp.ndarray, Tuple[int, int]]:
+    """NHWC -> ((N, OH*OW, kh*kw*C) patches, (OH, OW)).
+
+    K is ordered patch-position-major, channel-minor — the same layout
+    ``weight_hwio`` expects and build_* initializers produce.
+    """
+    n, c = x.shape[0], x.shape[3]
+    x, (oh, ow) = pad_window(x, kh, kw, stride, padding)
     patches = [x[:, i:i + (oh - 1) * stride + 1:stride,
                  j:j + (ow - 1) * stride + 1:stride, :]
                for i in range(kh) for j in range(kw)]
@@ -448,9 +479,8 @@ def graph_gemms(graph: OpGraph, in_hw,
 
     conv:      (OH*OW, kh*kw*C_in, C_out)
     depthwise: count=C instances of (OH*OW, kh*kw, 1) — the paper's
-               grouped accounting (models.cnn._dw); the executor fuses
-               them into one block-diagonal GEMM, same MACs modulo the
-               structural zeros it streams.
+               grouped accounting (models.cnn._dw); how the executor
+               runs them is ``LayerGemm.executed``.
     fc:        (1, H*W*C, D)
 
     Order matches the executor's walk exactly — schedule_cnn over this
@@ -475,21 +505,30 @@ def graph_gemms(graph: OpGraph, in_hw,
 # Forward walkers
 # ---------------------------------------------------------------------------
 def _global_mean(x: jnp.ndarray) -> jnp.ndarray:
-    """Global average pool as a fixed tree of pairwise adds.
+    """Global average pool as a fixed order of elementwise adds: the rows
+    in sequence, then the columns, zero-padded to a power of two, by
+    pairwise halving; then times 1/(H*W).
 
     A reduce sums in the order of the physical layout XLA gives its
     operand, and that layout depends on the surrounding program: on TPU a
     Pallas kernel's output and an XLA dot's operand get different ones.
     Two compilations of one network then disagree in the last bit of the
     pooled value, and so in the classifier's quantize scale.  Elementwise
-    adds in a fixed pairing round the same in every program.
+    adds in a fixed pairing round the same in every program.  The order
+    is the one XLA's reduce takes on a TPU v5e for an (N, H, W, C)
+    operand with W at most 8 (rows in sequence, the W sublanes by
+    halving), so a float32 ``jnp.mean`` over (H, W) there gives the same
+    bits: a flipped bit of a pooled value flips a classifier code.
     """
     n, h, w, c = x.shape
-    v = x.reshape(n, h * w, c)
+    v = x[:, 0]
+    for i in range(1, h):
+        v = v + x[:, i]
+    width = 1 << (w - 1).bit_length()
+    v = jnp.pad(v, ((0, 0), (0, width - w), (0, 0)))
     while v.shape[1] > 1:
         half = v.shape[1] // 2
-        v = jnp.concatenate([v[:, :half] + v[:, half:2 * half],
-                             v[:, 2 * half:]], axis=1)
+        v = v[:, :half] + v[:, half:]
     return (v * (1.0 / (h * w))).reshape(n, 1, 1, c)
 
 
@@ -531,13 +570,19 @@ def _apply_glue(node: OpNode, a: jnp.ndarray,
 
 def graph_forward(params: dict, x: jnp.ndarray, graph: OpGraph,
                   mm: Callable[[jnp.ndarray, jnp.ndarray, int, OpNode],
-                               jnp.ndarray]
+                               jnp.ndarray],
+                  dw: Optional[Callable[[jnp.ndarray, jnp.ndarray, int,
+                                         OpNode], jnp.ndarray]] = None
                   ) -> Dict[str, jnp.ndarray]:
     """Walk the graph; returns every node's output by name.
 
     ``mm(cols2d, weight, gemm_index, node)`` runs one lowered GEMM —
     the executor plugs the photonic kernel + per-layer plan/noise key in
     here; ``graph_apply`` plugs a plain (or photonic-reference) matmul.
+    ``dw(x, weight, gemm_index, node)``, when given, runs each depthwise
+    node on its (N, H, W, C) input with its compact (kh*kw, C) weight
+    and returns (N, OH, OW, C); without it depthwise nodes run as their
+    block-diagonal GEMM through ``mm``.
     All shape bookkeeping is static Python, so the walk traces into a
     single jax.jit program with zero host syncs.  Each node's body runs
     under ``jax.named_scope(node.name)``, so every device operation of a
@@ -553,7 +598,10 @@ def graph_forward(params: dict, x: jnp.ndarray, graph: OpGraph,
             continue
         a = vals[node.inputs[0]]
         with jax.named_scope(node.name):
-            if node.op in ("conv", "depthwise_conv"):
+            if node.op == "depthwise_conv" and dw is not None:
+                y = dw(a, params[node.name], gi, node)
+                gi += 1
+            elif node.op in ("conv", "depthwise_conv"):
                 wgt = params[node.name]
                 w2d = (depthwise_block_diag(wgt)
                        if node.op == "depthwise_conv" else wgt)

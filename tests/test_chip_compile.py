@@ -20,10 +20,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import hw
 from repro.core.types import Backend, Dataflow
-from repro.exec.scheduler import choose_tile
+from repro.exec.scheduler import choose_depthwise_tile, choose_tile
+from repro.kernels import taom_depthwise as tdw
 from repro.kernels.taom_gemm import (VMEM_BUDGET_BYTES, taom_gemm_quantized,
                                      vmem_bytes)
-from repro.models.lowering import LayerGemm
+from repro.models.lowering import LayerGemm, grouped_depthwise
 from repro.models.zoo_cnn import PAPER_ZOO
 
 BACKENDS = ("heana", "amw", "maw")
@@ -31,6 +32,12 @@ BACKENDS = ("heana", "amw", "maw")
 # batch 256 (block-diagonal depthwise, D=144) and a batch-256 3x3 conv
 # shape with 256 output channels.
 BIG_GEMMS = ((16384, 1296, 144), (65536, 576, 256))
+# MobileNetV2's depthwise layers at 32 images per call, (B, H, W, C, stride)
+# of the layer's input: the widest (mb16_0_dw), one at 56x56 with C=144
+# (mb24_1_dw) and the deepest (mb160_1_dw).
+MOBILENET_V2_B32_DW = {"mb16_0_dw": (32, 112, 112, 32, 1),
+                       "mb24_1_dw": (32, 56, 56, 144, 1),
+                       "mb160_1_dw": (32, 7, 7, 960, 1)}
 
 
 @pytest.fixture(scope="module")
@@ -85,14 +92,42 @@ def test_kernel_compiles_at_largest_admitted_tile(one_chip, backend, mkd):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_scheduler_tiles_fit_vmem_budget(backend):
-    """Host only: every GEMM of the four minis, batches 1-256."""
+    """Host only: every GEMM the TAOM GEMM kernel runs for the four
+    minis, batches 1-256 (grouped depthwise layers run the depthwise
+    kernel, whose steps tests/test_depthwise.py fits)."""
     n = hw.OperatingPoint.equal_area(backend, Dataflow.OS, 1.0).n
     for model in PAPER_ZOO.values():
         for g in model.gemms():
+            if g.depthwise and grouped_depthwise(backend):
+                continue
             for batch in range(1, 257):
                 m, k, d = LayerGemm(g.name, g.c * batch, g.k, g.d,
-                                    g.count).executed
+                                    g.count).executed(backend)
                 t = choose_tile(m, d, k, n)
                 assert vmem_bytes(t.block_m, t.block_d,
                                   n) <= VMEM_BUDGET_BYTES, (model.name,
                                                             g.name, batch, t)
+
+
+@pytest.mark.parametrize("layer", sorted(MOBILENET_V2_B32_DW))
+def test_depthwise_kernel_compiles_at_mobilenet_v2_b32(one_chip, layer):
+    """``taom_depthwise`` at the tile the scheduler plans, noise off (no
+    noise operand) and on."""
+    b, h, w, c, s = MOBILENET_V2_B32_DW[layer]
+    op = hw.OperatingPoint.equal_area("heana", Dataflow.OS, 1.0,
+                                      noise_enabled=False)
+    cfg = op.kernel_config()
+    tile = choose_depthwise_tile(b * h * w, c, 9, cfg.dpe_size)
+    hp, wp = h + 2, w + 2           # 'same' 3x3 at stride 1
+
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    for noise in (None, shape(b, h, w, c)):
+        fn = jax.jit(lambda x, wq, z: tdw.taom_depthwise_quantized(
+            x, wq, z, cfg=cfg, adc_fs=100.0, kh=3, kw=3, stride=s,
+            block_m=tile.block_m, block_d=tile.block_d))
+        text = fn.lower(shape(b, hp, wp, c), shape(9, c),
+                        noise).compile().as_text()
+        assert "%taom_depthwise." in text
+        assert "taom_analog_carry" not in text

@@ -20,6 +20,7 @@ from repro.exec import (PlanCache, compiled_forward, execute_cnn,
 from repro.exec.scheduler import choose_tile
 from repro.kernels import ops
 from repro.models import cnn
+from repro.models import lowering as lw
 from repro.models.cnn import (CNN_ZOO, LayerGemm, LoweredLayer,
                               build_small_cnn)
 
@@ -616,3 +617,24 @@ class TestReport:
             ).fps for f in Dataflow}
         cmp = plan_vs_fixed(plan, fixed)
         assert cmp["uplift"] >= 1.0 - 1e-12
+
+
+@pytest.mark.parametrize("hw", [(7, 7), (4, 4), (3, 5), (1, 1)])
+def test_global_mean_adds_rows_then_halves_columns(hw):
+    """The pool's add order, pinned: rows in sequence, then the columns,
+    zero-padded to a power of two, by pairwise halving (the order of
+    XLA's reduce on a TPU v5e, which bench/reference.py's jnp.mean
+    takes there), eagerly and under jit."""
+    h, w = hw
+    x = np.asarray(jax.random.uniform(jax.random.PRNGKey(h * 10 + w),
+                                      (3, h, w, 130)), np.float32)
+    v = x[:, 0].copy()
+    for i in range(1, h):
+        v = v + x[:, i]
+    width = 1 << (w - 1).bit_length()
+    v = np.concatenate([v, np.zeros((3, width - w, 130), np.float32)], 1)
+    while v.shape[1] > 1:
+        v = v[:, :v.shape[1] // 2] + v[:, v.shape[1] // 2:]
+    want = (v * np.float32(1.0 / (h * w))).reshape(3, 1, 1, 130)
+    for fn in (lw._global_mean, jax.jit(lw._global_mean)):
+        np.testing.assert_array_equal(np.asarray(fn(jnp.asarray(x))), want)

@@ -28,6 +28,7 @@ from repro.models import lowering as lw                     # noqa: E402
 from repro.models.lowering import LayerGemm                 # noqa: E402
 
 HEANA = pm.AcceleratorConfig.equal_area("heana", Dataflow.OS, 1.0)
+AMW = pm.AcceleratorConfig.equal_area("amw", Dataflow.OS, 1.0)
 
 
 @st.composite
@@ -135,14 +136,21 @@ def test_planned_tile_divides_its_gemm_dims(m, d, k):
     assert t.n_chunks >= 1
 
 
+@pytest.mark.parametrize("acc", [HEANA, AMW], ids=["heana", "amw"])
 @settings(max_examples=20, deadline=None)
 @given(st.integers(1, 4000), st.integers(1, 500), st.integers(1, 2000),
        st.integers(2, 64))
-def test_depthwise_plan_tile_covers_executed_gemm(m, d, k, count):
-    """Depthwise layers plan their tile against the fused block-diagonal
-    GEMM (M, k*count) @ (k*count, count) the executor actually runs."""
+def test_depthwise_plan_tile_covers_executed_gemm(acc, m, d, k, count):
+    """Depthwise layers plan their tile against what the executor
+    actually runs: the grouped taps (M pixels x count channels) under
+    analog carry, the fused block-diagonal GEMM (M, k*count) @
+    (k*count, count) under chunk ADC.  Either way the noise scale's
+    fold count is that of K = k*count."""
     layer = LayerGemm("dw", m, k, 1, count=count)
-    plan = plan_layer(layer, HEANA, cache=PlanCache())
+    plan = plan_layer(layer, acc, cache=PlanCache())
     assert plan.tile.grid_d * plan.tile.block_d >= count
     assert plan.tile.grid_m * plan.tile.block_m >= m
-    assert plan.tile.n_chunks == max(1, -(-k * count // HEANA.n))
+    assert plan.tile.n_chunks == max(1, -(-k * count // acc.n))
+    grouped = acc.backend == "heana"
+    assert layer.executed(acc.backend) == (
+        (m, k, count) if grouped else (m, k * count, count))

@@ -45,11 +45,11 @@ def _cfg(noise=False):
                           noise_enabled=noise)
 
 
-def _setup(model, batch=2, seed=0):
+def _setup(model, batch=2, seed=0, acc=HEANA):
     params = model.init_params(jax.random.PRNGKey(seed))
     x = jax.random.normal(jax.random.fold_in(jax.random.PRNGKey(seed), 1),
                           (batch, *model.in_hw, model.in_ch))
-    plan = plan_for_network(params, HEANA, batch=batch, in_hw=model.in_hw,
+    plan = plan_for_network(params, acc, batch=batch, in_hw=model.in_hw,
                             lowering=model.graph, cache=PlanCache())
     return params, x, plan
 
@@ -114,18 +114,24 @@ class TestZooConformance:
         assert [t.name for t in res.traces] == want
         assert all(t.latency_s > 0 for t in res.traces)
 
-    def test_depthwise_traces_report_executed_fused_gemm(self):
-        """LayerTrace is 'what actually ran': depthwise layers trace the
-        fused block-diagonal (M, kk*kk*C, C) GEMM — consistent with the
-        tile the scheduler sized — not the analytic per-group shape."""
+    @pytest.mark.parametrize("backend", ["heana", "amw"])
+    def test_depthwise_traces_report_executed_fused_gemm(self, backend):
+        """LayerTrace is 'what actually ran', consistent with the tile
+        the scheduler sized: under analog carry (HEANA) depthwise layers
+        trace the grouped (M, kk*kk, C) taps; under chunk ADC (AMW) the
+        fused block-diagonal (M, kk*kk*C, C) GEMM."""
         model = ZOO["mobilenet_mini"]
-        params, x, plan = _setup(model)
-        res = execute_cnn(params, x, plan, _cfg(), impl="ref",
+        acc = pm.AcceleratorConfig.equal_area(backend, Dataflow.OS, 1.0)
+        cfg = PhotonicConfig(backend=Backend(backend), bits=6,
+                             dpe_size=acc.n, noise_enabled=False)
+        params, x, plan = _setup(model, acc=acc)
+        res = execute_cnn(params, x, plan, cfg, impl="ref",
                           lowering=model.graph)
         trace = {t.name: t for t in res.traces}["ir2_dw"]
         lplan = {p.name: p for p in plan.layers}["ir2_dw"]
         assert lplan.count == 96 and lplan.d == 1 and lplan.k == 9
-        assert trace.k == 9 * 96 and trace.d == 96    # executed dims
+        k = 9 if backend == "heana" else 9 * 96
+        assert trace.k == k and trace.d == 96         # executed dims
         assert trace.m == lplan.c                     # rows unchanged
         assert trace.block_d == lplan.tile.block_d    # tile fits D=96
 
