@@ -129,64 +129,6 @@ def autoflow_table() -> str:
     return "\n".join(lines)
 
 
-def throughput_table() -> str:
-    """Compiled vs eager serving throughput (executor hot path), from
-    cached experiments/throughput JSONs (benchmarks/throughput.py)."""
-    recs = [r for r in _load("throughput") if r.get("kind") == "throughput"]
-    if not recs:
-        return "*(no cached runs — `PYTHONPATH=src python -m " \
-               "benchmarks.throughput` to generate)*"
-    lines = [
-        "| cnn | batch | compiled img/s | eager img/s | speedup | "
-        "warm ms/call | bit-exact | retraces (warm) |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    for r in sorted(recs, key=lambda r: (r["name"], r["batch"])):
-        warm = r.get("warm_s")
-        lines.append(
-            f"| {r['name']} | {r['batch']} | {_fmt(r['compiled_ips'])} | "
-            f"{_fmt(r['eager_ips'])} | {r['speedup']:.1f}x | "
-            f"{f'{warm * 1e3:.1f}' if warm is not None else '-'} | "
-            f"{r.get('bitexact', '-')} | "
-            f"{r.get('retraces_warm', '-')} |")
-    lines.append("")
-    lines.append("*img/s is host-simulation throughput (Pallas interpret "
-                 "on CPU) — it validates the software hot path, not the "
-                 "modeled photonic FPS.*")
-    return "\n".join(lines)
-
-
-def serving_table() -> str:
-    """Bucketed serving engine vs per-request execution, from cached
-    experiments/serving JSONs (benchmarks/serving.py)."""
-    recs = [r for r in _load("serving") if r.get("kind") == "serving"]
-    if not recs:
-        return "*(no cached runs — `PYTHONPATH=src python -m " \
-               "benchmarks.serving` to generate)*"
-    lines = [
-        "| cnn | devs | bucket | bucketed img/s | per-req img/s | speedup "
-        "| mean ms | padding | dp bit-exact | retraces (warm) |",
-        "|---|---|---|---|---|---|---|---|---|---|",
-    ]
-    for r in sorted(recs, key=lambda r: (r["name"],
-                                         r.get("n_devices") or 1)):
-        mean = r.get("latency_mean_s")
-        lines.append(
-            f"| {r['name']} | {r.get('n_devices', '-')} | {r['bucket']} | "
-            f"{_fmt(r['bucketed_ips'])} | "
-            f"{_fmt(r['per_request_ips'])} | {r['speedup']:.1f}x | "
-            f"{f'{mean * 1e3:.1f}' if mean is not None else '-'} | "
-            f"{_fmt(r.get('padding_fraction'))} | "
-            f"{r.get('dp_bitexact', '-')} | "
-            f"{r.get('retraces_warm', '-')} |")
-    lines.append("")
-    lines.append("*img/s is host-simulation throughput (Pallas interpret "
-                 "on CPU); dp bit-exact compares the NamedSharding "
-                 "data-parallel path against single-device logits "
-                 "(noise off).*")
-    return "\n".join(lines)
-
-
 def energy_table() -> str:
     """Executed-trace energy & FPS/W from one operating point, from
     cached experiments/energy JSONs (benchmarks/energy.py)."""
@@ -225,10 +167,6 @@ def main() -> None:
     print(roofline_table())
     print("\n## §Autoflow (per-layer dataflow auto-scheduler)\n")
     print(autoflow_table())
-    print("\n## §Throughput (compiled serving hot path)\n")
-    print(throughput_table())
-    print("\n## §Serving (bucketed multi-device serving engine)\n")
-    print(serving_table())
     print("\n## §Energy (executed-trace accounting from one operating "
           "point)\n")
     print(energy_table())

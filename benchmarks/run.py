@@ -51,8 +51,6 @@ MODULES = [
     ("fig13_14_bpca", "benchmarks.fig13_bpca_baselines"),
     ("table4_accuracy", "benchmarks.table4_accuracy"),
     ("autoflow", "benchmarks.autoflow"),
-    ("throughput", "benchmarks.throughput"),
-    ("serving", "benchmarks.serving"),
     ("energy", "benchmarks.energy"),
 ]
 

@@ -10,8 +10,7 @@ The three layers (see ISSUE 1 / paper §4, §6.3):
     pure forward (compiled_forward / forward_fn) with the plan's tilings
     baked in as static arguments and zero per-layer host syncs;
   * report    — modeled latency/energy aggregated next to executed
-    numerics, feeding benchmarks/autoflow.py, benchmarks/throughput.py
-    and examples;
+    numerics, feeding benchmarks/autoflow.py and examples;
 
 Hardware is specified ONCE: pass a core.hw.OperatingPoint wherever an
 AcceleratorConfig is accepted — the plan embeds it (plan v4), the
@@ -30,8 +29,7 @@ it via the same perf-model event accounting as the analytic figures
 Networks are described by the lowering IR (models.lowering.OpGraph —
 stride/padding convs, depthwise convs, pooling, residual adds, concats,
 channel shuffles); models.zoo_cnn registers reduced-scale runnable
-variants of the paper's four evaluation CNNs on it (ISSUE 3), and the
-legacy flat LoweredLayer tuples keep working.
+variants of the paper's four evaluation CNNs on it (ISSUE 3).
 """
 from repro.exec.executor import (ExecutionResult, LayerTrace,
                                  compile_cache_stats, compiled_forward,
@@ -41,8 +39,7 @@ from repro.exec.executor import (ExecutionResult, LayerTrace,
 from repro.exec.plan_cache import GLOBAL_PLAN_CACHE, PlanCache, fingerprint
 from repro.exec.report import (energy_summary, execution_summary,
                                graph_summary, plan_summary, plan_table,
-                               plan_vs_fixed, render_report, save_summary,
-                               serving_summary, throughput_summary)
+                               plan_vs_fixed, save_summary)
 from repro.exec.scheduler import (CnnPlan, FrozenCandidates, LayerPlan,
                                   TileChoice, plan_layer, schedule_buckets,
                                   schedule_cnn)
@@ -53,12 +50,10 @@ __all__ = [
     "CnnPlan", "FrozenCandidates", "LayerPlan", "TileChoice", "plan_layer",
     "schedule_cnn", "schedule_buckets",
     "ServingEngine", "MicroBatcher", "power_of_two_buckets", "bucket_for",
-    "serving_summary",
     "PlanCache", "GLOBAL_PLAN_CACHE", "fingerprint",
     "ExecutionResult", "LayerTrace", "execute_cnn", "plan_for_network",
     "reference_forward", "compiled_forward", "forward_fn", "trace_count",
     "compile_cache_stats", "lowering_fingerprint",
     "plan_summary", "plan_table", "plan_vs_fixed", "execution_summary",
-    "graph_summary", "render_report", "save_summary", "throughput_summary",
-    "energy_summary",
+    "graph_summary", "save_summary", "energy_summary",
 ]

@@ -2,8 +2,7 @@
 
 Runs a *runnable* GEMM-lowered CNN (a models.lowering.OpGraph — stride/
 padding convs, depthwise convs, pooling, residuals, concats, shuffles —
-or a legacy flat models.cnn.LoweredLayer tuple, + params dict)
-image-batch in, logits out, with every GEMM executed by
++ params dict) image-batch in, logits out, with every GEMM executed by
 kernels.ops.photonic_matmul: quantize -> TAOM kernel (Pallas; interpreted
 on CPU) -> rescale.  This turns the repo's analytic per-figure scripts
 into an actual inference engine producing real activations — the
@@ -38,9 +37,8 @@ loop must never stall on the host):
     fingerprint, plan cache keys, cfg, impl); jax.jit's own cache then
     keys the executable on the batch shape/dtype — repeated serving calls
     hit a traced executable;
-  * ``execute_cnn`` stays the thin eager-looking wrapper with today's
-    ExecutionResult API (``compiled=False`` opts back into the eager
-    op-by-op path, kept for the throughput benchmark's baseline).
+  * ``execute_cnn`` is the thin wrapper with the ExecutionResult API:
+    it validates, then calls the compiled forward.
 """
 from __future__ import annotations
 
@@ -48,7 +46,7 @@ import dataclasses
 import functools
 import threading
 from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,8 +64,8 @@ from repro.models import lowering as lw
 
 _LOWERING_FP_VERSION = 2
 
-#: A runnable network description: op-graph IR or legacy flat tuple.
-Lowering = Union[lw.OpGraph, Sequence[cnn_mod.LoweredLayer]]
+#: A runnable network description: the op-graph IR.
+Lowering = lw.OpGraph
 
 
 @dataclasses.dataclass
@@ -177,15 +175,9 @@ class ExecutionResult:
         return self
 
 
-def _norm_lowering(lowering):
-    """Default + normalize: None -> the small CNN; OpGraph passes
-    through; anything else is frozen into a legacy flat tuple (both
-    forms are hashable, as static jit arguments must be)."""
-    if lowering is None:
-        return cnn_mod.small_cnn_lowering()
-    if isinstance(lowering, lw.OpGraph):
-        return lowering
-    return tuple(lowering)
+def _norm_lowering(lowering: Optional[Lowering]) -> Lowering:
+    """Default: None -> the small CNN's graph."""
+    return lowering if lowering is not None else cnn_mod.small_cnn_graph()
 
 
 def _layer_matmul(cols: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
@@ -232,7 +224,7 @@ def executed_macs(plan: CnnPlan) -> Tuple[int, int]:
 # ---------------------------------------------------------------------------
 # Counts Python executions of the forward body.  Under jit the body runs
 # only while TRACING, so a warm compiled call leaves the counter untouched
-# — tests and benchmarks/throughput.py assert no-retrace with this.
+# — tests, ServingEngine and the chip benchmark count retraces with it.
 # Guarded by a lock: concurrent serving threads may trace simultaneously
 # (cold buckets), and ``count += 1`` is not atomic across the read/write —
 # a lost increment would let a real retrace slip past the no-retrace gates.
@@ -268,7 +260,7 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     global _TRACE_COUNT
     with _TRACE_LOCK:
         _TRACE_COUNT += 1
-    graph = cnn_mod.as_graph(lowering, plan=plan)
+    graph = lowering
 
     def layer_key(gi: int) -> Optional[jax.Array]:
         return (jax.random.fold_in(key, gi)
@@ -289,9 +281,8 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     gemm_outs = [vals[n.name] for n in graph.gemm_nodes]
     # mean |activation| via explicit reciprocal multiply — jnp.mean's
     # division by the (constant) element count is reassociated by XLA
-    # under jit but not eagerly, and the compiled-vs-eager contract
-    # covers the fingerprints too.  Each is named by its node, like the
-    # node's own operations.
+    # under jit but not eagerly; the explicit reciprocal gives one value
+    # in both.  Each is named by its node, like the node's own operations.
     fingerprints = []
     for node, v in zip(graph.gemm_nodes, gemm_outs):
         with jax.named_scope(node.name):
@@ -308,19 +299,12 @@ arguments static — CnnPlan/LayerPlan/TileChoice and PhotonicConfig are
 hashable by value precisely so they can sit in jit's cache key."""
 
 
-def lowering_fingerprint(lowering) -> str:
-    """Content address of a lowered network structure (not its weights).
-
-    Covers both forms: op graphs hash every node field; legacy flat
-    tuples keep their historical layout (under a bumped version — the
-    graph path changed what a lowering can express)."""
-    if isinstance(lowering, lw.OpGraph):
-        layers = [dataclasses.asdict(n) for n in lowering.nodes]
-        for d in layers:
-            d["inputs"] = list(d["inputs"])
-    else:
-        layers = [[l.name, l.kind, l.relu, l.pool_after, l.kk]
-                  for l in lowering]
+def lowering_fingerprint(lowering: Lowering) -> str:
+    """Content address of a lowered network structure (not its weights):
+    every node field of the op graph."""
+    layers = [dataclasses.asdict(n) for n in lowering.nodes]
+    for d in layers:
+        d["inputs"] = list(d["inputs"])
     return pc.fingerprint({"v": _LOWERING_FP_VERSION, "layers": layers})
 
 
@@ -392,21 +376,15 @@ def clear_compile_cache() -> None:
 # ---------------------------------------------------------------------------
 # Validation (eager, before tracing — clear errors instead of reshape noise)
 # ---------------------------------------------------------------------------
-def _gemm_count(lowering) -> int:
-    if isinstance(lowering, lw.OpGraph):
-        return len(lowering.gemm_nodes)
-    return len(lowering)
-
-
 def _validate(x: jnp.ndarray, plan: CnnPlan, cfg: PhotonicConfig,
-              lowering, key: Optional[jax.Array]) -> None:
+              lowering: Lowering, key: Optional[jax.Array]) -> None:
     if x.ndim != 4:
         raise ValueError(f"x must be (N, H, W, C) images, got shape "
                          f"{tuple(x.shape)}")
-    if len(plan.layers) != _gemm_count(lowering):
+    if len(plan.layers) != len(lowering.gemm_nodes):
         raise ValueError(
             f"plan has {len(plan.layers)} layers, lowering has "
-            f"{_gemm_count(lowering)} GEMM layers — plan the "
+            f"{len(lowering.gemm_nodes)} GEMM layers — plan the "
             f"lowered_gemms of this network")
     n, h, w = x.shape[0], x.shape[1], x.shape[2]
     if n != plan.batch:
@@ -436,20 +414,20 @@ def _validate(x: jnp.ndarray, plan: CnnPlan, cfg: PhotonicConfig,
 
 
 @functools.lru_cache(maxsize=_FORWARD_CACHE_MAX)
-def _validate_geometry(lowering, plan: CnnPlan, h: int, w: int) -> None:
+def _validate_geometry(graph: Lowering, plan: CnnPlan, h: int,
+                       w: int) -> None:
     """Structural checks, memoized: the outcome is fully determined by
-    (lowering, plan, H, W) — all hashable — so a warm serving loop pays
+    (graph, plan, H, W) — all hashable — so a warm serving loop pays
     the Python graph walk once per distinct geometry, not per call.
     (lru_cache does not cache raises: invalid combinations re-raise
     their clear error every call.)  Bounded like _FORWARD_CACHE — each
-    entry pins its plan/lowering — and cleared by clear_compile_cache.
+    entry pins its plan/graph — and cleared by clear_compile_cache.
 
     Infers every node's shape for THESE spatial dims — raising the IR's
     explicit errors for indivisible pooling / mismatched branches —
     then pins each GEMM node against its LayerPlan: the plan must have
     been built for exactly this input geometry.
     """
-    graph = cnn_mod.as_graph(lowering, plan=plan)
     shapes = lw.infer_shapes(graph, (h, w))
     for node, lplan in zip(graph.gemm_nodes, plan.layers):
         oh, ow, oc = shapes[node.name]
@@ -477,18 +455,18 @@ def _validate_geometry(lowering, plan: CnnPlan, h: int, w: int) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Public wrapper (today's ExecutionResult API)
+# Public wrapper (the ExecutionResult API)
 # ---------------------------------------------------------------------------
 def execute_cnn(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
                 plan: CnnPlan, cfg: PhotonicConfig,
                 key: Optional[jax.Array] = None,
                 impl: str = "auto",
                 lowering: Optional[Lowering] = None,
-                collect_activations: bool = False,
-                compiled: bool = True) -> ExecutionResult:
-    """Run a lowered CNN end-to-end through the photonic kernel.
+                collect_activations: bool = False) -> ExecutionResult:
+    """Run a lowered CNN end-to-end through the photonic kernel:
+    validate, then call the compiled forward (``compiled_forward``).
 
-    params: weight dict keyed by GEMM-node (or LoweredLayer) name.
+    params: weight dict keyed by GEMM-node name.
     x: (N, H, W, C) image batch (H != W is fine; the plan must have been
       built for the same spatial dims, see plan_for_network(in_hw=...)).
     plan: CnnPlan over lowered_gemms(params, lowering) at batch >= 1 —
@@ -498,23 +476,13 @@ def execute_cnn(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
       REQUIRED when cfg.noise_enabled, forbidden-to-matter otherwise.
     impl: 'pallas' | 'ref' | 'auto' (forwarded to ops.photonic_matmul).
     lowering: an op-graph (models.lowering.OpGraph — models.zoo_cnn holds
-      the paper networks' runnable variants) or a legacy flat
-      LoweredLayer tuple; defaults to the small CNN.
-    compiled: route through the jit-compiled forward (default).  False
-      runs the same body op-by-op in Python — the slow pre-fix behavior,
-      kept as the measurable baseline for benchmarks/throughput.py.
+      the paper networks' runnable variants); defaults to the small CNN.
     """
     lowering = _norm_lowering(lowering)
     impl = "pallas" if impl == "auto" else impl
     _validate(x, plan, cfg, lowering, key)
-    if compiled:
-        fn = compiled_forward(plan, cfg, lowering, impl,
-                              collect_activations)
-        logits, fingerprints, acts = fn(params, x, key)
-    else:
-        logits, fingerprints, acts = _forward(
-            params, x, key, lowering=lowering, plan=plan, cfg=cfg,
-            impl=impl, collect_activations=collect_activations)
+    fn = compiled_forward(plan, cfg, lowering, impl, collect_activations)
+    logits, fingerprints, acts = fn(params, x, key)
     return ExecutionResult(
         logits=logits, plan=plan, fingerprints=fingerprints,
         activations=list(acts) if collect_activations else None)

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, Iterable, Optional
+from typing import Dict, Optional
 
 from repro.core.types import Dataflow
 from repro.exec.executor import ExecutionResult
@@ -123,63 +123,6 @@ def execution_summary(res: ExecutionResult, name: str = "",
     return out
 
 
-def throughput_summary(name: str, batch: int, compiled_ips: float,
-                       eager_ips: float, modeled_fps: float,
-                       extras: Optional[dict] = None) -> dict:
-    """JSON-safe record of one compiled-vs-eager throughput measurement.
-
-    ``*_ips`` are measured warm-call images/sec on the host simulation;
-    ``modeled_fps`` is the photonic perf-model number for context (the
-    two are different machines — never compare them directly).
-    """
-    out = {
-        "kind": "throughput",
-        "name": name,
-        "batch": batch,
-        "compiled_ips": compiled_ips,
-        "eager_ips": eager_ips,
-        "speedup": (compiled_ips / eager_ips) if eager_ips > 0
-        else float("inf"),
-        "modeled_fps": modeled_fps,
-    }
-    if extras:
-        out.update(extras)
-    return out
-
-
-def serving_summary(name: str, batch_bucket: int, engine_stats: dict,
-                    bucketed_ips: float, per_request_ips: float,
-                    extras: Optional[dict] = None) -> dict:
-    """JSON-safe record of one serving-engine measurement.
-
-    ``bucketed_ips`` is the engine's sustained warm throughput for this
-    cell; ``per_request_ips`` is the single-image-at-a-time baseline
-    (batch-1 plan, one compiled call per image) the bucketed path is
-    amortizing away.  ``engine_stats`` is ServingEngine.stats() — the
-    padding, service-time and cache evidence rides along verbatim.
-    """
-    out = {
-        "kind": "serving",
-        "name": name,
-        "bucket": batch_bucket,
-        "bucketed_ips": bucketed_ips,
-        "per_request_ips": per_request_ips,
-        "speedup": (bucketed_ips / per_request_ips) if per_request_ips > 0
-        else float("inf"),
-        "latency_mean_s": engine_stats.get("latency_mean_s"),
-        "device_wait_s_total": engine_stats.get("device_wait_s_total"),
-        "padding_fraction": engine_stats.get("padding_fraction"),
-        "retraces_since_warmup": engine_stats.get("retraces_since_warmup"),
-        "data_parallel": engine_stats.get("data_parallel"),
-        "n_devices": engine_stats.get("n_devices"),
-        "plan_cache": engine_stats.get("plan_cache"),
-        "compile_cache": engine_stats.get("compile_cache"),
-    }
-    if extras:
-        out.update(extras)
-    return out
-
-
 def energy_summary(name: str, op, executed, analytic,
                    extras: Optional[dict] = None) -> dict:
     """JSON-safe record of one executed-trace energy measurement.
@@ -209,23 +152,6 @@ def energy_summary(name: str, op, executed, analytic,
         "fpsw_rel_gap": rel(executed.fps_per_watt, analytic.fps_per_watt),
         **({} if not extras else dict(extras)),
     }
-
-
-def render_report(summaries: Iterable[dict]) -> str:
-    """Markdown table over plan summaries (one row per CNN/config)."""
-    lines = [
-        "| cnn | backend | batch | fps | fps/W | mix (os/is/ws) | "
-        "cache h/m |",
-        "|---|---|---|---|---|---|---|",
-    ]
-    for s in summaries:
-        mix = s["dataflow_mix"]
-        lines.append(
-            f"| {s['name']} | {s['backend']} | {s['batch']} | "
-            f"{s['fps']:.1f} | {s['fps_per_watt']:.2f} | "
-            f"{mix.get('os', 0)}/{mix.get('is', 0)}/{mix.get('ws', 0)} | "
-            f"{s['cache_hits']}/{s['cache_misses']} |")
-    return "\n".join(lines)
 
 
 def save_summary(summary: dict, directory: str, filename: str) -> str:

@@ -26,7 +26,7 @@ implies:
 
   * **warmup()** — pre-traces every (bucket, sharding) executable with a
     dummy batch, so no serving request ever pays a trace (zero retraces
-    after warmup is asserted by benchmarks/serving.py and CI);
+    after warmup is asserted by the tests);
 
   * a thread-safe **micro-batcher** — coalesces single-image requests
     from a queue into bucketed batches under a max-delay knob, reading
@@ -41,7 +41,7 @@ implies:
     rows (shard_map).  Because the contraction (K) axis is never sharded
     and the global quantize-scale max becomes an exact all-reduce max,
     the data-parallel logits are BITWISE equal to single-device
-    execution when noise is off (benchmarks/serving.py checks this on 4
+    execution when noise is off (tests/test_serving.py checks this on 4
     virtual CPU devices, chip_smoke.py --chips 4 on four TPU chips);
 
   * **serving metrics** — always-on counters (mean service time and
@@ -130,7 +130,7 @@ class ServingEngine:
         A ``cfg`` whose bits/DPE geometry disagrees with the plans'
         hardware is rejected HERE, at construction — not after the first
         mis-modeled request.
-    lowering : op-graph / legacy tuple; default small CNN.
+    lowering : op graph (models.lowering.OpGraph); default small CNN.
     in_hw : input spatial size (int or (H, W)).
     max_batch : largest bucket (rounded up to a power of two).  Larger
         requests are chunked into top-bucket pieces.
@@ -162,8 +162,7 @@ class ServingEngine:
         self._lowering = ex._norm_lowering(lowering)
         self._in_hw = ((in_hw, in_hw) if isinstance(in_hw, int)
                        else (int(in_hw[0]), int(in_hw[1])))
-        self._in_ch = cnn_mod.as_graph(self._lowering,
-                                       params=params).input.cout
+        self._in_ch = self._lowering.input.cout
         self.buckets = power_of_two_buckets(max_batch)
         self.plan_cache = (plan_cache if plan_cache is not None
                            else pc.PlanCache())

@@ -17,18 +17,14 @@ published block structures at 224x224 input.
 (forward-pass) CNN used by the Table 4 accuracy experiments, whose conv
 layers execute through the photonic GEMM simulation.
 
-Runnable lowerings come in two shapes:
-
-  * the general op-graph IR (models.lowering.OpGraph) — stride/padding
-    convs, depthwise convs, pooling, residual adds, concats, channel
-    shuffles; the paper's four evaluation networks have reduced-scale
-    runnable variants built on it in models.zoo_cnn;
-  * the legacy flat ``LoweredLayer`` tuple (conv/fc chains), kept as a
-    convenience and converted to a graph internally (``as_graph``).
+Runnable networks are described by the op-graph IR (models.lowering.
+OpGraph): stride/padding convs, depthwise convs, pooling, residual adds,
+concats, channel shuffles.  ``small_cnn_graph`` is the small CNN on it;
+the paper's four evaluation networks have reduced-scale runnable
+variants in models.zoo_cnn.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -175,43 +171,9 @@ def total_macs(layers: List[LayerGemm]) -> int:
 # ---------------------------------------------------------------------------
 # GEMM lowering hooks (consumed by repro.exec — the execution engine)
 # ---------------------------------------------------------------------------
-@dataclasses.dataclass(frozen=True)
-class LoweredLayer:
-    """One GEMM-lowered layer of a *runnable* CNN.
-
-    ``name`` doubles as the params-dict key holding the (K, D) weight
-    matrix.  ``kind`` selects the input transform: 'conv' applies the
-    kk x kk im2col (SAME padding, stride 1) before the GEMM; 'fc' flattens
-    the feature map into a single row per image.  ``relu``/``pool_after``
-    describe the digital post-GEMM stages (activation unit / pooling unit
-    in the accelerator's tile, Fig. 10).
-    """
-    name: str
-    kind: str                 # 'conv' | 'fc'
-    relu: bool = True
-    pool_after: bool = False  # 2x2 max pool, stride 2
-    kk: int = 3
-
-
-def small_cnn_lowering() -> tuple:
-    """The GEMM-lowering of build_small_cnn/small_cnn_apply, layer by layer.
-
-    Kept next to the forward pass so the two cannot drift: the executor
-    (repro.exec.executor) replays exactly this structure through the Pallas
-    kernel, and tests pin it against small_cnn_apply.  This is the legacy
-    flat form; ``small_cnn_graph`` is the same network as op-graph IR.
-    """
-    return (
-        LoweredLayer("conv1", "conv", relu=True, pool_after=True),
-        LoweredLayer("conv2", "conv", relu=True, pool_after=True),
-        LoweredLayer("conv3", "conv", relu=True, pool_after=False),
-        LoweredLayer("fc", "fc", relu=False, pool_after=False),
-    )
-
-
 def small_cnn_graph(num_classes: int = 10, in_ch: int = 3) -> OpGraph:
-    """build_small_cnn as op-graph IR (identical numerics to the legacy
-    flat lowering: conv-relu-pool, conv-relu-pool, conv-relu, fc)."""
+    """build_small_cnn as op-graph IR: conv-relu-pool, conv-relu-pool,
+    conv-relu, fc."""
     return OpGraph((
         lw.input_node(in_ch),
         lw.conv("conv1", "input", 16),
@@ -223,84 +185,19 @@ def small_cnn_graph(num_classes: int = 10, in_ch: int = 3) -> OpGraph:
     ))
 
 
-def _spatial_dims(in_hw) -> tuple:
-    """Normalize a spatial-size spec: int -> square, (H, W) -> as given.
-
-    Delegates to lowering.spatial_dims, which validates the spec
-    explicitly (length, positivity) instead of failing downstream."""
-    return lw.spatial_dims(in_hw)
-
-
-def graph_from_layers(layers, channels: Dict[str, int],
-                      in_ch: int) -> OpGraph:
-    """Convert a legacy flat LoweredLayer tuple into the op-graph IR.
-
-    ``channels`` maps layer name -> output channels (read off weights or
-    a plan — the flat form never carried them).  pool_after becomes an
-    explicit 2x2/2 max-pool node named ``<layer>.pool``.
-    """
-    nodes = [lw.input_node(in_ch)]
-    prev = "input"
-    for lyr in layers:
-        d = channels[lyr.name]
-        if lyr.kind == "conv":
-            nodes.append(lw.conv(lyr.name, prev, d, kk=lyr.kk,
-                                 relu=lyr.relu))
-        elif lyr.kind == "fc":
-            nodes.append(lw.fc(lyr.name, prev, d, relu=lyr.relu))
-        else:
-            raise ValueError(f"unknown lowered-layer kind: {lyr.kind!r}")
-        prev = lyr.name
-        if lyr.pool_after:
-            nodes.append(lw.pool(f"{lyr.name}.pool", prev))
-            prev = f"{lyr.name}.pool"
-    return OpGraph(tuple(nodes))
-
-
-def as_graph(lowering, params: Optional[dict] = None,
-             plan=None) -> OpGraph:
-    """Normalize any runnable lowering to the op-graph IR.
-
-    OpGraphs pass through; legacy flat tuples need channel counts, read
-    from ``params`` weight shapes (preferred) or a CnnPlan's per-layer
-    ``d`` — the executor's compiled wrapper has a plan but no params.
-    """
-    if isinstance(lowering, OpGraph):
-        return lowering
-    layers = tuple(lowering)
-    if params is not None:
-        channels = {l.name: int(params[l.name].shape[1]) for l in layers}
-    elif plan is not None:
-        channels = {l.name: p.d for l, p in zip(layers, plan.layers)}
-    else:
-        raise ValueError("converting a legacy flat lowering needs params "
-                         "or a plan to recover channel counts")
-    first = layers[0]
-    if first.kind != "conv":
-        raise ValueError(
-            f"legacy flat lowerings must start with a conv layer to "
-            f"recover C_in (got {first.kind!r}) — build an OpGraph with "
-            f"an explicit input node instead")
-    if params is not None:
-        in_ch = int(params[first.name].shape[0]) // (first.kk * first.kk)
-    else:
-        in_ch = next(p.k for p in plan.layers) // (first.kk * first.kk)
-    return graph_from_layers(layers, channels, in_ch)
-
-
-def lowered_gemms(params: dict, lowering=None, in_hw=16) -> List[LayerGemm]:
+def lowered_gemms(params: dict, graph: Optional[OpGraph] = None,
+                  in_hw=16) -> List[LayerGemm]:
     """Analytic GEMM table (for the scheduler) of a lowered runnable CNN.
 
-    Walks the lowering (op-graph or legacy flat tuple), tracking spatial
-    size through strides and pools, validating every weight shape against
-    the graph — the same (C, K, D) the executor will feed the kernel, so
+    Walks the op graph (default: the small CNN), tracking spatial size
+    through strides and pools, validating every weight shape against the
+    graph — the same (C, K, D) the executor will feed the kernel, so
     plans and execution agree.
 
     ``in_hw`` is the input spatial size: an int for square images or an
     (H, W) pair for rectangular ones (conv rows become H_out*W_out).
     """
-    graph = as_graph(lowering or small_cnn_lowering(), params=params)
-    return lw.graph_gemms(graph, in_hw, params=params)
+    return lw.graph_gemms(graph or small_cnn_graph(), in_hw, params=params)
 
 
 # ---------------------------------------------------------------------------
@@ -323,36 +220,27 @@ def build_small_cnn(key: jax.Array, num_classes: int = 10,
     }
 
 
-def _im2col(x: jnp.ndarray, kk: int = 3) -> jnp.ndarray:
-    """NHWC -> (N, H*W, C*kk*kk) patches with SAME padding (stride 1).
-
-    Legacy shim over lowering.im2col (which also handles stride/padding
-    and returns the output extent)."""
-    cols, _ = lw.im2col(x, kk, kk, stride=1, padding="same")
-    return cols
-
-
-def lowered_apply(params: dict, x: jnp.ndarray, lowering=None,
+def lowered_apply(params: dict, x: jnp.ndarray,
+                  graph: Optional[OpGraph] = None,
                   matmul: Optional[Callable] = None) -> jnp.ndarray:
-    """Forward pass of ANY lowered runnable CNN, driven by its lowering.
+    """Forward pass of ANY lowered runnable CNN, driven by its op graph
+    (default: the small CNN).
 
-    The single source of truth for what a lowering computes — op-graph
-    IR or legacy flat tuple: the executor (repro.exec.executor) replays
-    exactly this structure through the Pallas kernel, and the
-    bit-exactness oracle (exec.executor.reference_forward) calls this
-    with the *same* lowering the executor ran — so the contract covers
-    every lowered network (stride/depthwise/residual/pool included), not
-    just the small CNN.
+    The single source of truth for what a graph computes: the executor
+    (repro.exec.executor) replays exactly this structure through the
+    Pallas kernel, and the bit-exactness oracle (exec.executor.
+    reference_forward) calls this with the *same* graph the executor
+    ran — so the contract covers every lowered network (stride/
+    depthwise/residual/pool included), not just the small CNN.
 
     ``matmul(a, w)`` defaults to exact and can be the photonic simulation
     (ops.photonic_matmul partial).  Rectangular images are first-class.
     """
-    graph = as_graph(lowering or small_cnn_lowering(), params=params)
-    return lw.graph_apply(params, x, graph, matmul)
+    return lw.graph_apply(params, x, graph or small_cnn_graph(), matmul)
 
 
 def small_cnn_apply(params: dict, x: jnp.ndarray,
                     matmul: Optional[Callable] = None) -> jnp.ndarray:
     """Forward pass of the small CNN; delegates to ``lowered_apply`` with
-    its own lowering so forward and lowering cannot drift."""
-    return lowered_apply(params, x, small_cnn_lowering(), matmul)
+    its own graph so forward and lowering cannot drift."""
+    return lowered_apply(params, x, small_cnn_graph(), matmul)

@@ -75,7 +75,7 @@ def test_grouped_equals_block_diagonal(backend, impl, noise, stride,
 
 @pytest.mark.parametrize("impl", ["ref", "pallas"])
 def test_grouped_equals_block_diagonal_eagerly(impl):
-    """Op by op, as the executor's eager path runs it."""
+    """Op by op, outside jit."""
     cfg = _cfg("heana", True)
     x = jax.random.normal(jax.random.PRNGKey(12), (2, 9, 10, 24))
     w = jax.random.normal(jax.random.PRNGKey(13), (9, 24))

@@ -1,7 +1,8 @@
 """Execution-engine tests: scheduler optimality, plan cache, executor
 parity with the pure-jnp oracle (paper §4/§6.3 — flexible dataflows),
-and the jit-compiled serving hot path (ISSUE 2): compiled == eager
-bitwise, zero retraces on warm calls, lazy trace materialization."""
+and the jit-compiled serving hot path (ISSUE 2): kernel == oracle
+bitwise under one compilation, zero retraces on warm calls, lazy trace
+materialization."""
 import dataclasses
 import json
 
@@ -21,8 +22,7 @@ from repro.exec.scheduler import choose_tile
 from repro.kernels import ops
 from repro.models import cnn
 from repro.models import lowering as lw
-from repro.models.cnn import (CNN_ZOO, LayerGemm, LoweredLayer,
-                              build_small_cnn)
+from repro.models.cnn import CNN_ZOO, LayerGemm, build_small_cnn
 
 HEANA = pm.AcceleratorConfig.equal_area("heana", Dataflow.OS, 1.0)
 AMW = pm.AcceleratorConfig.equal_area("amw", Dataflow.WS, 1.0)
@@ -217,11 +217,13 @@ def _custom_lowering():
     """A runnable network that is NOT the small CNN: two convs (one 5x5),
     one pool, fc — exercises the lowering-driven oracle (ISSUE 2 satellite:
     reference_forward used to hardcode small_cnn_apply)."""
-    return (
-        LoweredLayer("ca", "conv", relu=True, pool_after=True, kk=3),
-        LoweredLayer("cb", "conv", relu=True, pool_after=False, kk=5),
-        LoweredLayer("out", "fc", relu=False, pool_after=False),
-    )
+    return lw.OpGraph((
+        lw.input_node(2),
+        lw.conv("ca", "input", 8, kk=3),
+        lw.pool("ca.pool", "ca"),
+        lw.conv("cb", "ca.pool", 12, kk=5),
+        lw.fc("out", "cb", 5),
+    ))
 
 
 def _custom_params(key, in_hw=8, in_ch=2):
@@ -236,7 +238,8 @@ def _custom_params(key, in_hw=8, in_ch=2):
 
 
 class TestCompiledForward:
-    """The serving hot path: jit-compiled forward == eager, no retraces."""
+    """The serving hot path: the compiled Pallas forward equals the
+    compiled oracle forward bitwise, and warm calls never retrace."""
 
     def _setup(self, batch=3, noise=False, bits=6):
         key = jax.random.PRNGKey(0)
@@ -252,27 +255,25 @@ class TestCompiledForward:
     @pytest.mark.parametrize("noise", [False, True])
     @pytest.mark.parametrize("batch", [1, 3])
     def test_compiled_bit_exact_vs_eager_pallas(self, noise, batch):
-        """Acceptance: the compiled forward is bit-exact vs the eager
-        op-by-op path, noise on and off."""
+        """Acceptance: kernel == oracle under one compilation — the
+        compiled impl='pallas' forward equals the compiled impl='ref'
+        forward bitwise (same key), noise on and off."""
         params, x, cfg, plan = self._setup(batch=batch, noise=noise)
         key = jax.random.PRNGKey(11) if noise else None
         c = execute_cnn(params, x, plan, cfg, key=key, impl="pallas")
-        e = execute_cnn(params, x, plan, cfg, key=key, impl="pallas",
-                        compiled=False)
+        r = execute_cnn(params, x, plan, cfg, key=key, impl="ref")
         np.testing.assert_array_equal(np.asarray(c.logits),
-                                      np.asarray(e.logits))
-        # fingerprints are diagnostics: same program, but reduction order
-        # may differ between fused/eager reduces — tight tolerance only
-        # (per-GEMM-node fingerprints reduce over the pre-pool tensors,
-        # so the fused/eager divergence is a few ULP larger than before)
+                                      np.asarray(r.logits))
+        # fingerprints are diagnostics: the two programs may fuse their
+        # reduces differently — tight tolerance only
         np.testing.assert_allclose(np.asarray(c.fingerprints),
-                                   np.asarray(e.fingerprints), rtol=5e-6)
+                                   np.asarray(r.fingerprints), rtol=5e-6)
 
     @pytest.mark.parametrize("noise", [False, True])
     def test_compiled_bit_exact_vs_eager_batch256(self, noise):
-        """Acceptance at the serving batch (256) — ref impl keeps the
-        eager baseline affordable in CI (benchmarks/throughput.py covers
-        the Pallas impl at 256); tilings come from the batch-256 plan."""
+        """Acceptance at the serving batch (256): compiled impl='pallas'
+        == compiled impl='ref' bitwise, both on the batch-256 plan's
+        tilings."""
         params = build_small_cnn(jax.random.PRNGKey(0))
         x = jax.random.normal(jax.random.PRNGKey(2), (256, 16, 16, 3))
         cfg = PhotonicConfig(backend=Backend.HEANA, bits=6, dpe_size=83,
@@ -280,23 +281,25 @@ class TestCompiledForward:
         plan = plan_for_network(params, HEANA, batch=256,
                                 cache=PlanCache())
         key = jax.random.PRNGKey(11) if noise else None
-        c = execute_cnn(params, x, plan, cfg, key=key, impl="ref")
-        e = execute_cnn(params, x, plan, cfg, key=key, impl="ref",
-                        compiled=False)
+        c = execute_cnn(params, x, plan, cfg, key=key, impl="pallas")
+        r = execute_cnn(params, x, plan, cfg, key=key, impl="ref")
         np.testing.assert_array_equal(np.asarray(c.logits),
-                                      np.asarray(e.logits))
+                                      np.asarray(r.logits))
 
-    def test_no_retrace_on_repeated_calls(self):
+    @pytest.mark.parametrize("batch", [1, 3, 32, 256])
+    def test_no_retrace_on_repeated_calls(self, batch):
         """Acceptance: warm compiled calls never re-trace (the pre-fix
-        executor re-traced every inference)."""
-        params, x, cfg, plan = self._setup()
+        executor re-traced every inference), at every batch of the grid
+        from one image to the serving batch."""
+        params, x, cfg, plan = self._setup(batch=batch)
         execute_cnn(params, x, plan, cfg)           # cold: traces once
         before = trace_count()
         for _ in range(3):
             execute_cnn(params, x, plan, cfg)
         assert trace_count() == before
         # a replanned (equal) plan must hit the same executable
-        plan2 = plan_for_network(params, HEANA, batch=3, cache=PlanCache())
+        plan2 = plan_for_network(params, HEANA, batch=batch,
+                                 cache=PlanCache())
         execute_cnn(params, x, plan2, cfg)
         assert trace_count() == before
 
@@ -310,13 +313,6 @@ class TestCompiledForward:
         assert trace_count() == before + 1          # one new shape: 1 trace
         execute_cnn(params, x8, plan8, cfg)
         assert trace_count() == before + 1
-
-    def test_eager_path_runs_python_body_every_call(self):
-        params, x, cfg, plan = self._setup()
-        before = trace_count()
-        execute_cnn(params, x, plan, cfg, compiled=False)
-        execute_cnn(params, x, plan, cfg, compiled=False)
-        assert trace_count() == before + 2
 
     def test_compiled_forward_memo_shares_wrapper(self):
         """Equal planning problems (distinct plan objects) share one

@@ -4,9 +4,12 @@ Pins the serving contracts: bucket selection, padding bit-exactness
 (a padded request equals the exact-size batch), chunking semantics,
 zero retraces after warmup, thread-safety of concurrent serving (and of
 the executor's module caches it leans on), micro-batcher plumbing and
-error propagation, noise-key handling, data-parallel bit-identity (when
-several devices are visible), and the metrics/stats surface.
+error propagation, noise-key handling, data-parallel bit-identity (on 4
+host devices, in a child process), and the metrics/stats surface.
 """
+import os
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -379,24 +382,50 @@ class TestDataParallel:
             ServingEngine(params, HEANA, _cfg(noise=True), max_batch=4,
                           plan_cache=PlanCache(), data_parallel=True)
 
-    @pytest.mark.skipif(len(jax.devices()) < 2,
-                        reason="needs >= 2 devices (run under XLA_FLAGS="
-                               "--xla_force_host_platform_device_count=4)")
-    def test_dp_bitwise_equals_single_device(self, served):
-        key, params, engine = served
-        n_dev = len(jax.devices())
-        if engine.max_bucket % n_dev:
-            pytest.skip(f"max bucket {engine.max_bucket} not divisible "
-                        f"by {n_dev} devices")
-        dp = ServingEngine(params, HEANA, _cfg(), max_batch=8,
-                           plan_cache=engine.plan_cache,
-                           data_parallel=True)
-        dp.warmup()
-        x = _images(key, 950, 8)
-        np.testing.assert_array_equal(
-            np.asarray(jax.device_get(dp.infer(x))),
-            np.asarray(jax.device_get(engine.infer(x))))
-        assert dp.stats()["data_parallel"] is True
+    def test_dp_bitwise_equals_single_device(self):
+        """Data-parallel serving over 4 devices returns logits bitwise
+        equal to one device (noise off).  Runs in a child process: the
+        host platform's device count is fixed when JAX starts."""
+        env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=" ".join(
+            f for f in (os.environ.get("XLA_FLAGS", ""),
+                        "--xla_force_host_platform_device_count=4") if f))
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (_SRC, os.environ.get("PYTHONPATH", "")) if p)
+        out = subprocess.run([sys.executable, "-c", _DP_CHILD], env=env,
+                             capture_output=True, text=True, timeout=600)
+        assert out.returncode == 0, out.stderr[-4000:]
+        assert out.stdout.split()[-1] == "dp_bitwise_ok", out.stdout
+
+
+_SRC = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "src")
+
+# Serves one 8-image batch on one device and data-parallel over four,
+# from engines sharing one plan cache, and compares the logits bitwise.
+_DP_CHILD = """
+import jax
+import numpy as np
+from repro.core import perf_model as pm
+from repro.core.types import Backend, Dataflow, PhotonicConfig
+from repro.exec import PlanCache, ServingEngine
+from repro.models.cnn import build_small_cnn
+
+assert len(jax.devices()) == 4, jax.devices()
+acc = pm.AcceleratorConfig.equal_area("heana", Dataflow.OS, 1.0)
+cfg = PhotonicConfig(backend=Backend.HEANA, bits=6, dpe_size=83,
+                     noise_enabled=False)
+key = jax.random.PRNGKey(0)
+params = build_small_cnn(key)
+cache = PlanCache()
+single = ServingEngine(params, acc, cfg, max_batch=8, plan_cache=cache)
+dp = ServingEngine(params, acc, cfg, max_batch=8, plan_cache=cache,
+                   data_parallel=True)
+assert dp.stats()["data_parallel"] is True
+x = jax.random.normal(jax.random.fold_in(key, 950), (8, 16, 16, 3))
+np.testing.assert_array_equal(np.asarray(jax.device_get(dp.infer(x))),
+                              np.asarray(jax.device_get(single.infer(x))))
+print("dp_bitwise_ok")
+"""
 
 
 class TestGmean:

@@ -16,7 +16,7 @@ CNN):
     resnet_mini are checked in — a kernel/scheduler refactor that
     silently changes numerics fails loudly.
 
-Plus the explicit spatial-validation contract (the old `_spatial_dims`/
+Plus the explicit spatial-validation contract (the old spatial-dims/
 pooling code assumed even square dims and failed with reshape noise).
 """
 import json
@@ -224,20 +224,20 @@ class TestGoldenTrace:
 
 
 class TestSpatialValidation:
-    """Satellite bugfix: `_spatial_dims`/pooling used to assume even
+    """Satellite bugfix: spatial dims/pooling used to assume even
     square dims — stride-2 and odd-dimension handling is now explicit."""
 
     def test_spatial_dims_validates_spec(self):
-        assert cnn._spatial_dims(16) == (16, 16)
-        assert cnn._spatial_dims((16, 8)) == (16, 8)
+        assert lw.spatial_dims(16) == (16, 16)
+        assert lw.spatial_dims((16, 8)) == (16, 8)
         with pytest.raises(ValueError, match=r"\(H, W\) pair"):
-            cnn._spatial_dims((16,))
+            lw.spatial_dims((16,))
         with pytest.raises(ValueError, match=r"\(H, W\) pair"):
-            cnn._spatial_dims((16, 8, 3))
+            lw.spatial_dims((16, 8, 3))
         with pytest.raises(ValueError, match="positive"):
-            cnn._spatial_dims(0)
+            lw.spatial_dims(0)
         with pytest.raises(ValueError, match="positive"):
-            cnn._spatial_dims((16, -8))
+            lw.spatial_dims((16, -8))
 
     def test_stride2_conv_handles_odd_dims_explicitly(self):
         """SAME-padded stride-2 convs on odd/rect inputs are first-class
