@@ -199,6 +199,25 @@ def _layer_depthwise(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
                                   block_d=plan.tile.block_d, mesh=mesh)
 
 
+def _layer_small_cin(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
+                     key: Optional[jax.Array], plan: LayerPlan,
+                     node: lw.OpNode, impl: str,
+                     mesh: Optional[Mesh]) -> jnp.ndarray:
+    return ops.photonic_conv_small_cin(x, w, cfg, key=key, impl=impl,
+                                       kh=node.kh, kw=node.kw,
+                                       stride=node.stride,
+                                       padding=node.padding,
+                                       block_m=plan.tile.block_m,
+                                       block_d=plan.tile.block_d, mesh=mesh)
+
+
+def small_cin_rows(plan: CnnPlan, lowering: Lowering) -> int:
+    """GEMM rows one call of ``plan``'s program builds from the quantized
+    image (``lowering.small_cin_conv``), padding images included."""
+    return sum(p.c for node, p in zip(lowering.gemm_nodes, plan.layers)
+               if lw.small_cin_conv(node, p.k // (node.kh * node.kw)))
+
+
 def executed_macs(plan: CnnPlan) -> Tuple[int, int]:
     """(all layers, depthwise layers): the MACs one call of ``plan``'s
     program performs as laid out.  A TAOM GEMM kernel runs K in
@@ -248,7 +267,9 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     GEMM-bearing node (conv / depthwise_conv / fc) runs through the
     photonic kernel with its LayerPlan's tiling and an independent noise
     key — depthwise nodes of an analog-carry policy through the grouped
-    depthwise kernel (ops.photonic_depthwise); glue nodes (pool /
+    depthwise kernel (ops.photonic_depthwise), convs of at most
+    ``lowering.SMALL_CIN_MAX`` input channels from their quantized image
+    (ops.photonic_conv_small_cin); glue nodes (pool /
     residual_add / concat / shuffle / slice) are plain jnp ops.
     Everything after the array arguments is static configuration; no
     host sync happens anywhere in the body (fingerprints stay device
@@ -276,8 +297,14 @@ def _forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
         return _layer_depthwise(a, w, cfg, layer_key(gi), plan.layers[gi],
                                 node, impl, mesh)
 
+    def small_cin(a: jnp.ndarray, w: jnp.ndarray, gi: int,
+                  node: lw.OpNode) -> jnp.ndarray:
+        return _layer_small_cin(a, w, cfg, layer_key(gi), plan.layers[gi],
+                                node, impl, mesh)
+
     vals = lw.graph_forward(params, x, graph, mm,
-                            dw if lw.grouped_depthwise(cfg.backend) else None)
+                            dw if lw.grouped_depthwise(cfg.backend) else None,
+                            small_cin)
     gemm_outs = [vals[n.name] for n in graph.gemm_nodes]
     # mean |activation| via explicit reciprocal multiply — jnp.mean's
     # division by the (constant) element count is reassociated by XLA

@@ -225,6 +225,12 @@ class ServingEngine:
                              for b in self.buckets}
         self._executed_macs = 0
         self._depthwise_macs = 0
+        # GEMM rows each bucket's program builds from the quantized image
+        # (the small-C_in route), padding images included.
+        self._bucket_small_cin_rows = {
+            b: ex.small_cin_rows(self.plans[b], self._lowering)
+            for b in self.buckets}
+        self._small_cin_rows = 0
         self._energy_j = 0.0
         self._model_time_s = 0.0
 
@@ -276,6 +282,7 @@ class ServingEngine:
             self._batches += 1
             self._executed_macs += macs
             self._depthwise_macs += dw_macs
+            self._small_cin_rows += self._bucket_small_cin_rows[bucket]
             self._padded_slots += pad
             self._executed_slots += bucket
             self._energy_j += te.energy_j
@@ -405,6 +412,9 @@ class ServingEngine:
                 # diagonal zeros included), and its depthwise part.
                 "executed_macs_total": self._executed_macs,
                 "depthwise_executed_macs_total": self._depthwise_macs,
+                # GEMM rows the dispatched programs built from the
+                # quantized image (executor.small_cin_rows).
+                "small_cin_rows_total": self._small_cin_rows,
                 "buckets": list(self.buckets),
                 "data_parallel": self.data_parallel,
                 "n_devices": len(self.devices),

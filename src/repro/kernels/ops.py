@@ -4,7 +4,10 @@
 kernel or the pure-jnp oracle (kernels run in interpret mode on CPU), and
 attaches the straight-through-estimator VJP so photonic numerics are
 trainable.  ``photonic_depthwise`` does the same for a depthwise layer
-under an analog-carry policy, bitwise its block-diagonal GEMM.
+under an analog-carry policy, bitwise its block-diagonal GEMM, and
+``photonic_conv_small_cin`` for a dense conv whose input has few channels
+(a stem), bitwise its im2col GEMM: it builds the patches from the
+quantized image with one one-hot convolution.
 
 ``ssd_scan`` is the Mamba2 scan entry point: the Pallas kernel for the
 serving/prefill hot path, and a differentiable chunked jnp implementation
@@ -17,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.photonic_gemm import (ANALOG_CARRY_BACKENDS, noise_shape,
@@ -37,12 +41,13 @@ def _on_cpu() -> bool:
 # ---------------------------------------------------------------------------
 # photonic_matmul
 # ---------------------------------------------------------------------------
-def _taom_forward(x2d: jnp.ndarray, w: jnp.ndarray, noise: jnp.ndarray,
-                  cfg: PhotonicConfig, adc_fs: float, impl: str,
-                  blocks: tuple, mesh: Optional[Mesh]) -> jnp.ndarray:
-    f32 = jnp.float32
-    xq, sx = quantize(x2d.astype(f32), cfg.bits, axis=None)
-    wq, sw = quantize(w.astype(f32), cfg.bits, axis=0)
+def _taom_core(xq: jnp.ndarray, sx: jnp.ndarray, wq: jnp.ndarray,
+               sw: jnp.ndarray, noise: jnp.ndarray, cfg: PhotonicConfig,
+               adc_fs: float, impl: str, blocks: tuple,
+               mesh: Optional[Mesh], dtype) -> jnp.ndarray:
+    """The TAOM GEMM of (M, K) activation codes and (K, D) weight codes,
+    rescaled by their scales: what every dense layer runs, however its
+    codes were laid out."""
     if impl == "pallas":
         kernel = functools.partial(
             taom_kernel_mod.taom_gemm_quantized, cfg=cfg, adc_fs=adc_fs,
@@ -50,7 +55,7 @@ def _taom_forward(x2d: jnp.ndarray, w: jnp.ndarray, noise: jnp.ndarray,
         if mesh is not None:
             # XLA cannot partition a Mosaic kernel: each device runs it on
             # its own rows.  Rows are independent, so this is bitwise the
-            # unsharded kernel; the quantize max above stays global.
+            # unsharded kernel; the quantize max stays global.
             rows = P(mesh.axis_names)
             kernel = jax.shard_map(
                 kernel, mesh=mesh,
@@ -68,8 +73,18 @@ def _taom_forward(x2d: jnp.ndarray, w: jnp.ndarray, noise: jnp.ndarray,
     # the compiled forward bit-identical to eager execution (the
     # executor's compiled-vs-eager contract; free at runtime).
     acc, sx, sw = jax.lax.optimization_barrier((acc, sx, sw))
-    out = (acc * (sx * sw)).astype(x2d.dtype)
+    out = (acc * (sx * sw)).astype(dtype)
     return jax.lax.optimization_barrier(out)
+
+
+def _taom_forward(x2d: jnp.ndarray, w: jnp.ndarray, noise: jnp.ndarray,
+                  cfg: PhotonicConfig, adc_fs: float, impl: str,
+                  blocks: tuple, mesh: Optional[Mesh]) -> jnp.ndarray:
+    f32 = jnp.float32
+    xq, sx = quantize(x2d.astype(f32), cfg.bits, axis=None)
+    wq, sw = quantize(w.astype(f32), cfg.bits, axis=0)
+    return _taom_core(xq, sx, wq, sw, noise, cfg, adc_fs, impl, blocks,
+                      mesh, x2d.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -88,6 +103,20 @@ def _taom_ste_bwd(cfg, adc_fs, impl, blocks, mesh, res, g):
 
 
 _taom_ste.defvjp(_taom_ste_fwd, _taom_ste_bwd)
+
+
+def _gemm_noise(key: Optional[jax.Array], x_shape: tuple, w_shape: tuple,
+               cfg: PhotonicConfig) -> jnp.ndarray:
+    """The kernel's noise operand for an (M, K) @ (K, D) GEMM: standard
+    normal draws, or zeros without noise; (M, D) under analog carry,
+    (C, M, D) under a chunk-ADC policy."""
+    if key is not None and cfg.noise_enabled:
+        noise = sample_noise(key, x_shape, w_shape, cfg)
+    else:
+        noise = jnp.zeros(noise_shape(x_shape, w_shape, cfg), jnp.float32)
+    if cfg.backend in (Backend.AMW, Backend.MAW):
+        noise = jnp.moveaxis(noise, -2, 0)   # (..., C, D) -> (C, M, D)
+    return noise
 
 
 def photonic_matmul(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
@@ -137,12 +166,7 @@ def photonic_matmul(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
         adc_fs = taom_kernel_mod.calibrated_adc_fs(x.shape[-1], cfg)
     batch_shape = x.shape[:-1]
     x2d = x.reshape(-1, x.shape[-1])
-    if key is not None and cfg.noise_enabled:
-        noise = sample_noise(key, x2d.shape, w.shape, cfg)
-    else:
-        noise = jnp.zeros(noise_shape(x2d.shape, w.shape, cfg), jnp.float32)
-    if cfg.backend in (Backend.AMW, Backend.MAW):
-        noise = jnp.moveaxis(noise, -2, 0)   # (..., C, D) -> (C, M, D)
+    noise = _gemm_noise(key, x2d.shape, w.shape, cfg)
     out = _taom_ste(x2d, w, noise, cfg, float(adc_fs), impl,
                     (int(block_m), int(block_d)), mesh)
     return out.reshape(*batch_shape, w.shape[-1])
@@ -154,8 +178,9 @@ def photonic_matmul(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
 def _sampled_absmax(xp: jnp.ndarray, kh: int, kw: int, stride: int,
                     oh: int, ow: int) -> jnp.ndarray:
     """max |x| over the padded pixels the layer's windows read: the
-    values its block-diagonal GEMM's im2col holds.  With the stride at
-    most the window, they are one rectangle from the top-left corner."""
+    values its im2col holds (a depthwise layer's block-diagonal GEMM's
+    too).  With the stride at most the window, they are one rectangle
+    from the top-left corner."""
     s = stride
     if s <= kh and s <= kw:
         return jnp.max(jnp.abs(xp[:, :(oh - 1) * s + kh, :(ow - 1) * s + kw]))
@@ -211,17 +236,21 @@ def _depthwise_ste_fwd(x, w, noise, cfg, adc_fs, impl, geometry, blocks,
                                blocks, mesh), (x, w))
 
 
+def _exact_conv(x: jnp.ndarray, w: jnp.ndarray, geometry: tuple,
+                groups: int) -> jnp.ndarray:
+    """The exact conv of NHWC ``x`` with a tap-major (kh*kw*C/groups, D)
+    weight: the straight-through gradient of the photonic convs."""
+    kh, kw, stride, padding = geometry
+    return jax.lax.conv_general_dilated(
+        x, w.reshape(kh, kw, -1, w.shape[-1]), (stride, stride),
+        padding.upper(), dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=groups)
+
+
 def _depthwise_ste_bwd(cfg, adc_fs, impl, geometry, blocks, mesh, res, g):
     x, w = res
-    kh, kw, stride, padding = geometry
-
-    def exact(x, w):
-        return jax.lax.conv_general_dilated(
-            x, w.reshape(kh, kw, 1, w.shape[-1]), (stride, stride),
-            padding.upper(), dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=x.shape[-1])
-
-    dx, dw = jax.vjp(exact, x, w)[1](g.astype(x.dtype))
+    dx, dw = jax.vjp(lambda x, w: _exact_conv(x, w, geometry, x.shape[-1]),
+                     x, w)[1](g.astype(x.dtype))
     return dx, dw, None
 
 
@@ -273,6 +302,99 @@ def photonic_depthwise(x: jnp.ndarray, w: jnp.ndarray, cfg: PhotonicConfig,
                              cfg).reshape(b, oh, ow, c)
     return _depthwise_ste(x, w, noise, cfg, float(adc_fs), impl,
                           (int(kh), int(kw), int(stride), str(padding)),
+                          (int(block_m), int(block_d)), mesh)
+
+
+# ---------------------------------------------------------------------------
+# photonic_conv_small_cin
+# ---------------------------------------------------------------------------
+def _small_cin_forward(x, w, noise, cfg: PhotonicConfig, adc_fs: float,
+                       impl: str, geometry: tuple, blocks: tuple,
+                       mesh: Optional[Mesh]) -> jnp.ndarray:
+    kh, kw, stride, padding = geometry
+    f32 = jnp.float32
+    c = x.shape[-1]
+    k = kh * kw * c
+    xp, (oh, ow) = pad_window(x.astype(f32), kh, kw, stride, padding)
+    xq, sx = quantize(xp, cfg.bits, absmax=_sampled_absmax(
+        xp, kh, kw, stride, oh, ow))
+    # Codes of up to 8 bits, and the one-hot kernel, are exact in
+    # bfloat16: each output is one code times 1.0 plus zeros, so the
+    # patches keep that type (half the bytes of their relayout into the
+    # kernel's chunk slots).
+    dt = jnp.bfloat16 if cfg.bits <= 8 else f32
+    onehot = np.eye(k, dtype=np.float32).reshape(kh, kw, c, k)
+    cols = jax.lax.conv_general_dilated(
+        xq.astype(dt), jnp.asarray(onehot, dt), (stride, stride), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=dt)
+    wq, sw = quantize(w.astype(f32), cfg.bits, axis=0)
+    out = _taom_core(cols.reshape(-1, k), sx, wq, sw, noise, cfg, adc_fs,
+                     impl, blocks, mesh, x.dtype)
+    return out.reshape(x.shape[0], oh, ow, w.shape[-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _small_cin_ste(x, w, noise, cfg, adc_fs, impl, geometry, blocks, mesh):
+    return _small_cin_forward(x, w, noise, cfg, adc_fs, impl, geometry,
+                              blocks, mesh)
+
+
+def _small_cin_ste_fwd(x, w, noise, cfg, adc_fs, impl, geometry, blocks,
+                       mesh):
+    return (_small_cin_forward(x, w, noise, cfg, adc_fs, impl, geometry,
+                               blocks, mesh), (x, w))
+
+
+def _small_cin_ste_bwd(cfg, adc_fs, impl, geometry, blocks, mesh, res, g):
+    x, w = res
+    dx, dw = jax.vjp(lambda x, w: _exact_conv(x, w, geometry, 1),
+                     x, w)[1](g.astype(x.dtype))
+    return dx, dw, None
+
+
+_small_cin_ste.defvjp(_small_cin_ste_fwd, _small_cin_ste_bwd)
+
+
+def photonic_conv_small_cin(x: jnp.ndarray, w: jnp.ndarray,
+                            cfg: PhotonicConfig,
+                            key: Optional[jax.Array] = None,
+                            impl: str = "auto", *, kh: int, kw: int,
+                            stride: int = 1, padding: str = "same",
+                            block_m: int = 128, block_d: int = 128,
+                            mesh: Optional[Mesh] = None) -> jnp.ndarray:
+    """Photonic-numerics dense conv of an input with few channels: (B, H,
+    W, C) images with a tap-major (kh*kw*C, D) weight -> (B, OH, OW, D).
+
+    Bitwise what ``photonic_matmul`` gives on the layer's im2col, for
+    every policy: the padded image is quantized once (the max over the
+    pixels the windows read is im2col's max, and quantize is
+    elementwise), and the (M, kh*kw*C) patches are built from its codes
+    by one convolution with a one-hot kernel that sends tap (i, j, c) to
+    column (i*kw + j)*C + c, im2col's K order.  The noise, the ADC full
+    scale ``calibrated_adc_fs(kh*kw*C)``, the kernel and the rescale are
+    the GEMM's.  With C on the lanes, im2col's 49 strided slices and
+    their concatenate would each be a lane-sparse pass over the image;
+    the convolution reads the codes once.  Gradients are those of the
+    exact conv (straight-through).  ``block_m``/``block_d``: the GEMM
+    kernel's tile; ``mesh``: the images of ``x`` are sharded over it.
+    """
+    geometry = (int(kh), int(kw), int(stride), str(padding))
+    if cfg.backend == Backend.EXACT:
+        return _exact_conv(x, w, geometry, 1)
+    if cfg.noise_enabled and key is None:
+        raise ValueError(
+            "photonic_conv_small_cin: cfg.noise_enabled=True but key=None "
+            "— pass a PRNG key, or set noise_enabled=False")
+    if impl == "auto":
+        impl = "pallas"
+    b, h, wd, c = x.shape
+    k = kh * kw * c
+    rows = (b * conv_out_dim(h, kh, stride, padding)
+            * conv_out_dim(wd, kw, stride, padding))
+    adc_fs = taom_kernel_mod.calibrated_adc_fs(k, cfg)
+    noise = _gemm_noise(key, (rows, k), w.shape, cfg)
+    return _small_cin_ste(x, w, noise, cfg, float(adc_fs), impl, geometry,
                           (int(block_m), int(block_d)), mesh)
 
 

@@ -57,6 +57,20 @@ PADDINGS = ("same", "valid")
 # ---------------------------------------------------------------------------
 # Analytic GEMM record (the scheduler/perf-model currency)
 # ---------------------------------------------------------------------------
+#: The most input channels a dense conv may have to take the small-C_in
+#: route (``small_cin_conv``).
+SMALL_CIN_MAX = 8
+
+
+def small_cin_conv(node: "OpNode", cin: int) -> bool:
+    """Does ``node`` build its GEMM operand from the quantized image
+    (kernels.ops.photonic_conv_small_cin) instead of ``im2col``?  Dense
+    convs whose input has at most ``SMALL_CIN_MAX`` channels do: with C
+    on the lanes, each of im2col's kh*kw slices is a lane-sparse pass
+    over the image."""
+    return node.op == "conv" and cin <= SMALL_CIN_MAX
+
+
 def grouped_depthwise(backend) -> bool:
     """Do ``backend``'s depthwise layers run as grouped kh*kw-tap reads
     (kernels.taom_depthwise)?  Under analog carry each output is one
@@ -417,7 +431,11 @@ def im2col(x: jnp.ndarray, kh: int, kw: int, stride: int = 1,
     """NHWC -> ((N, OH*OW, kh*kw*C) patches, (OH, OW)).
 
     K is ordered patch-position-major, channel-minor — the same layout
-    ``weight_hwio`` expects and build_* initializers produce.
+    ``weight_hwio`` expects and build_* initializers produce.  The
+    oracle (``graph_apply``) lowers every conv through here; the
+    executor does so for convs wider than ``SMALL_CIN_MAX`` channels,
+    and builds a stem's patches from codes in this same K order
+    (``small_cin_conv``).
     """
     n, c = x.shape[0], x.shape[3]
     x, (oh, ow) = pad_window(x, kh, kw, stride, padding)
@@ -572,7 +590,10 @@ def graph_forward(params: dict, x: jnp.ndarray, graph: OpGraph,
                   mm: Callable[[jnp.ndarray, jnp.ndarray, int, OpNode],
                                jnp.ndarray],
                   dw: Optional[Callable[[jnp.ndarray, jnp.ndarray, int,
-                                         OpNode], jnp.ndarray]] = None
+                                         OpNode], jnp.ndarray]] = None,
+                  small_cin: Optional[Callable[[jnp.ndarray, jnp.ndarray,
+                                                int, OpNode],
+                                               jnp.ndarray]] = None
                   ) -> Dict[str, jnp.ndarray]:
     """Walk the graph; returns every node's output by name.
 
@@ -583,6 +604,11 @@ def graph_forward(params: dict, x: jnp.ndarray, graph: OpGraph,
     node on its (N, H, W, C) input with its compact (kh*kw, C) weight
     and returns (N, OH, OW, C); without it depthwise nodes run as their
     block-diagonal GEMM through ``mm``.
+    ``small_cin(x, weight, gemm_index, node)``, when given, runs each
+    conv that ``small_cin_conv`` routes (at most ``SMALL_CIN_MAX`` input
+    channels) on its (N, H, W, C) input with its (kh*kw*C, D) weight and
+    returns (N, OH, OW, D); without it, and for wider convs, the conv
+    runs as its ``im2col`` GEMM through ``mm``.
     All shape bookkeeping is static Python, so the walk traces into a
     single jax.jit program with zero host syncs.  Each node's body runs
     under ``jax.named_scope(node.name)``, so every device operation of a
@@ -600,6 +626,10 @@ def graph_forward(params: dict, x: jnp.ndarray, graph: OpGraph,
         with jax.named_scope(node.name):
             if node.op == "depthwise_conv" and dw is not None:
                 y = dw(a, params[node.name], gi, node)
+                gi += 1
+            elif small_cin is not None and small_cin_conv(node,
+                                                          a.shape[-1]):
+                y = small_cin(a, params[node.name], gi, node)
                 gi += 1
             elif node.op in ("conv", "depthwise_conv"):
                 wgt = params[node.name]

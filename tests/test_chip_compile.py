@@ -12,6 +12,7 @@ one process at a time may load the TPU library, and pytest-xdist workers
 all import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,10 +22,12 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import hw
 from repro.core.types import Backend, Dataflow
 from repro.exec.scheduler import choose_depthwise_tile, choose_tile
+from repro.kernels import ops
 from repro.kernels import taom_depthwise as tdw
 from repro.kernels.taom_gemm import (VMEM_BUDGET_BYTES, taom_gemm_quantized,
                                      vmem_bytes)
-from repro.models.lowering import LayerGemm, grouped_depthwise
+from repro.models.lowering import (SMALL_CIN_MAX, LayerGemm, conv_out_dim,
+                                   grouped_depthwise)
 from repro.models.zoo_cnn import PAPER_ZOO
 
 BACKENDS = ("heana", "amw", "maw")
@@ -131,3 +134,64 @@ def test_depthwise_kernel_compiles_at_mobilenet_v2_b32(one_chip, layer):
                         noise).compile().as_text()
         assert "%taom_depthwise." in text
         assert "taom_analog_carry" not in text
+
+
+# One instruction of an optimized HLO module: name, result dtype,
+# dimensions and layout (minor to major), opcode, and the op_name of its
+# metadata.
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\{([\d,]*)\S* "
+    r'([a-z][a-z0-9-]*)\(.*op_name="([^"]*)"')
+# Instructions that move no data.
+_NO_PASS = ("bitcast", "constant", "parameter", "get-tuple-element")
+
+
+def _scope_passes(text, scope):
+    """(name, extent of the minor dimension, opcode) of the passes the
+    entry computation makes (fusion bodies are parts of one pass) whose
+    op_name lies in the named scope ``scope``.  The minor dimension is
+    the layout's first, the one a TPU lays on its 128 lanes."""
+    entry = text[text.index("\nENTRY "):]
+    out = []
+    for line in entry[:entry.index("\n}")].splitlines():
+        m = _INSTRUCTION.match(line)
+        if (m and m.group(2) and m.group(4) not in _NO_PASS
+                and scope in m.group(5).split("/")):
+            dims = [int(d) for d in m.group(2).split(",")]
+            minor = int(m.group(3).split(",")[0])
+            out.append((m.group(1), dims[minor], m.group(4)))
+    return out
+
+
+def test_resnet50_conv1_builds_its_patches_without_lane_sparse_passes(
+        one_chip, monkeypatch):
+    """ResNet-50's conv1 (224x224x3, 7x7/2 SAME, 64 channels) at bucket
+    8 through the small-C_in route, with the Mosaic kernel: its compiled
+    scope holds no per-tap concatenate, no pass whose minor dimension is
+    the image's 3 channels, and at most one lane-sparse pass (at most 8
+    entries on the lanes: the relayout of the quantized image for the
+    one-hot convolution, which puts the batch there)."""
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    cfg = hw.OperatingPoint.equal_area("heana", Dataflow.OS, 1.0,
+                                       noise_enabled=False).kernel_config()
+    b, side, c, kk, stride, d = 8, 224, 3, 7, 2, 64
+    oh = conv_out_dim(side, kk, stride, "same")
+    tile = choose_tile(b * oh * oh, d, kk * kk * c, cfg.dpe_size)
+
+    def conv1(x, w):
+        with jax.named_scope("conv1"):
+            return ops.photonic_conv_small_cin(
+                x, w, cfg, kh=kk, kw=kk, stride=stride, padding="same",
+                block_m=tile.block_m, block_d=tile.block_d)
+
+    def shape(*s):
+        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(conv1).lower(shape(b, side, side, c),
+                                shape(kk * kk * c, d)).compile().as_text()
+    passes = _scope_passes(text, "conv1")
+    assert "custom-call" in [op for _, _, op in passes]
+    assert [n for n, _, op in passes if op == "concatenate"] == []
+    assert [n for n, lanes, _ in passes if lanes == c] == []
+    sparse = [n for n, lanes, _ in passes if lanes <= SMALL_CIN_MAX]
+    assert len(sparse) <= 1, sparse
